@@ -24,6 +24,7 @@ corrupts tokens/s silently."""
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU-only tool: never take a chip
 import argparse
 import json
 import sys
@@ -77,7 +78,7 @@ MEASURED_GRID = [
 
 def measure(cfg, seq, batch, tmp_degree, schedule, fine, iters=3):
     dp = 8 // tmp_degree
-    mesh = jax.make_mesh((dp, tmp_degree), ("data", "model"))
+    mesh = compat.make_mesh((dp, tmp_degree), ("data", "model"))
     hp = TrainHParams(schedule=schedule, fine_remat=fine, microbatch=1)
     fn, specs = steps_mod.build_train_step(cfg, mesh, hp,
                                            global_batch=batch, seq_len=seq)
@@ -90,7 +91,7 @@ def measure(cfg, seq, batch, tmp_degree, schedule, fine, iters=3):
          "labels": jax.random.randint(k, (batch, seq), 0, cfg.vocab_size,
                                       jnp.int32)}
     step = jax.jit(fn)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params, opt, m = step(params, opt, b)
         jax.block_until_ready(m["loss"])
         t0 = time.perf_counter()

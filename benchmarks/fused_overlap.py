@@ -57,7 +57,7 @@ def build_step(mesh, schedule, *, layers, batch, seq, d_model, d_ff):
             total = total + jnp.sum(s)
         return total
 
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=((P(None, None, ("model",)), P(None, ("model",), None)),
                   P(("data",), None, None)),
@@ -119,7 +119,7 @@ def main():
         fn, ws, x = build_step(mesh, sched, layers=args.layers,
                                batch=args.batch, seq=args.seq,
                                d_model=args.d_model, d_ff=args.d_ff)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             t = measure(fn, ws, x, args.iters)
         results[sched] = t
         base = base or t
@@ -139,7 +139,7 @@ def main():
     fn, ws, x = build_step(mesh, "megatron", layers=args.layers,
                            batch=args.batch, seq=args.seq,
                            d_model=args.d_model, d_ff=args.d_ff)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         txt = jax.jit(fn).lower(ws, x).compile().as_text()
     cost = hlo_cost.analyze(txt, default_group=4)
     rf = cost.roofline_seconds(peak_flops=V5E.peak_flops,

@@ -83,8 +83,7 @@ def run():
     from repro.configs.registry import get_config
     from repro.core import compat
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     cfg = get_config("internlm2-1.8b").reduced().replace(dtype="float32")
     prompts = _prompts(cfg.vocab_size)
 

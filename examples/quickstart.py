@@ -5,7 +5,6 @@
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
 from repro.configs.base import TrainHParams
 from repro.configs.registry import get_config
 from repro.core.axes import mesh_info
@@ -32,7 +31,7 @@ k = jax.random.PRNGKey(1)
 batch = {"tokens": jax.random.randint(k, (4, 64), 0, cfg.vocab_size),
          "labels": jax.random.randint(k, (4, 64), 0, cfg.vocab_size)}
 step = jax.jit(step_fn)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     for i in range(10):
         params, opt, m = step(params, opt, batch)
         print(f"step {i}: loss {float(m['loss']):.4f}")
@@ -40,7 +39,7 @@ with compat.set_mesh(mesh):
 # 3. serve: prefill a prompt, decode a few tokens greedily
 pf, _, _ = lm.build_prefill(cfg, mesh, hp, global_batch=4, seq_len=64)
 df, _, _ = lm.build_decode(cfg, mesh, hp, global_batch=4, seq_len=64)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     tok, state = jax.jit(pf)(params, {"tokens": batch["tokens"]})
     outs = [int(t) for t in tok]
     pos = jnp.full((4,), 63, jnp.int32)

@@ -1,103 +1,24 @@
-"""JAX version compatibility shims.
+"""Mesh construction with ``Auto`` axis types.
 
-The codebase targets the current jax API (``jax.shard_map``,
-``jax.set_mesh``, ``jax.make_mesh(..., axis_types=...)``,
-``pltpu.CompilerParams``); CI and several deployment targets still run
-jax 0.4.x where those live under older names.  Everything version-dependent
-is funneled through this module so call sites stay on the modern spelling.
-
-Covered:
-
-* ``make_mesh``       — ``axis_types=`` kwarg appeared after 0.4.x; older
-                        jax has no axis types, so the kwarg is dropped.
-* ``shard_map``       — ``jax.shard_map(..., check_vma=)`` vs
-                        ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-* ``set_mesh``        — ``jax.set_mesh`` vs entering the ``Mesh`` context
-                        manager directly (sufficient for explicit-mesh
-                        ``shard_map`` callees).
-* ``tpu_compiler_params`` — ``pltpu.CompilerParams`` was called
-                        ``pltpu.TPUCompilerParams`` on 0.4.x.
+``jax.make_mesh`` defaults to ``Explicit`` axis types, under which
+``with_sharding_constraint`` may only name ``Auto`` axes.  This codebase
+shards through ``with_sharding_constraint`` and ``shard_map`` with the
+mesh's axes left to the compiler, so every mesh is built here, ``Auto``
+on every axis unless the caller asks otherwise.
 """
 from __future__ import annotations
 
-import contextlib
-import functools
-from typing import Any, Optional, Sequence
+from typing import Optional, Sequence
 
 import jax
-
-
-@functools.lru_cache(maxsize=1)
-def _make_mesh_supports_axis_types() -> bool:
-    import inspect
-    return "axis_types" in inspect.signature(jax.make_mesh).parameters
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-              *, axis_types: Optional[Sequence[Any]] = None,
+              *, axis_types: Optional[Sequence[AxisType]] = None,
               devices=None):
-    """``jax.make_mesh`` accepting (and dropping, pre-AxisType jax) the
-    ``axis_types`` kwarg.  Support is probed by signature, not by catching
-    TypeError — a malformed ``axis_types`` on modern jax must surface, not
-    silently degrade to default axis types."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None and _make_mesh_supports_axis_types():
-        kwargs["axis_types"] = tuple(axis_types)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-def auto_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` where AxisType exists, else None."""
-    try:
-        from jax.sharding import AxisType
-        return (AxisType.Auto,) * n
-    except ImportError:
-        return None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Modern ``jax.shard_map`` signature, falling back to
-    ``jax.experimental.shard_map`` (where ``check_vma`` was ``check_rep``).
-
-    Known 0.4.x caveat the model code works around: under ``grad`` +
-    scan + a remat policy, a RANK-0 scan-carry residual crossing the
-    shard_map boundary gets mis-assigned full axis names and crashes with
-    ``_SpecError`` (fixed in later jax).  All scalar scan carries inside
-    shard_map bodies are therefore kept rank-1 ``(1,)`` (see
-    ``models/lm.py`` and ``core/tmp.py``).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh`` context; on older jax the ``Mesh`` object itself is
-    the context manager that installs it as the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext()
-
-
-def axis_size(a) -> int:
-    """Static mesh-axis size; ``lax.psum(1, a)`` constant-folds to the axis
-    size on jax versions without ``lax.axis_size``."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(a)
-    return lax.psum(1, a)
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new) / ``pltpu.TPUCompilerParams`` (0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+    """``jax.make_mesh`` with ``AxisType.Auto`` on every axis by default."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=tuple(axis_types), devices=devices)

@@ -124,11 +124,11 @@ def pipeline_apply(stage_fn: Callable, x_micro, *, pipe_axis: str, pp: int,
     """Drive the circular interleaved pipeline schedule.
 
     ``stage_fn(c, x) -> (y, aux)`` runs this device's virtual-stage chunk
-    ``c`` on microbatch tensor ``x``; ``aux`` is a rank-1 ``(1,)`` f32
+    ``c`` on microbatch tensor ``x``; ``aux`` is a scalar f32
     accumulator (auxiliary losses).  ``x_micro`` is ``[n_micro, mb, ...]``,
     identical on every pipe shard (batch-sharded over the data axes only).
 
-    Returns ``(out [n_micro, mb, ...], aux [1])`` where ``out`` holds the
+    Returns ``(out [n_micro, mb, ...], aux)`` where ``out`` holds the
     fully-processed microbatches on the LAST stage's shards (other shards
     carry zeros-derived garbage — mask with :func:`mask_to_last_stage`
     before the loss) and ``aux`` holds this shard's stages' masked
@@ -177,7 +177,7 @@ def pipeline_apply(stage_fn: Callable, x_micro, *, pipe_axis: str, pp: int,
         return (buf, aux_total), out_t
 
     buf0 = jnp.zeros((v,) + tuple(x_micro.shape[1:]), x_micro.dtype)
-    aux0 = jnp.zeros((1,), jnp.float32)
+    aux0 = jnp.zeros((), jnp.float32)
     (_, aux_total), ys = lax.scan(
         tick, (buf0, aux0), jnp.arange(n_micro + stages - 1,
                                        dtype=jnp.int32))

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from repro.core.compat import axis_size as _axis_size
 
 COLLECTIVE_NAME = "oases_collective"
 Axes = Tuple[str, ...]
@@ -118,7 +117,7 @@ def batch_split(x, axes: Axes, dim: int):
     if not axes:
         return x
     import math
-    sz = math.prod(_axis_size(a) for a in axes)
+    sz = math.prod(lax.axis_size(a) for a in axes)
     chunk = x.shape[dim] // sz
     return lax.dynamic_slice_in_dim(x, axes_index(axes) * chunk, chunk,
                                     axis=dim)
@@ -137,7 +136,7 @@ def _bs_bwd(axes, dim, _, g):
     if not axes:
         return (g,)
     import math
-    sz = math.prod(_axis_size(a) for a in axes)
+    sz = math.prod(lax.axis_size(a) for a in axes)
     chunk = g.shape[dim]
     full_shape = g.shape[:dim] + (chunk * sz,) + g.shape[dim + 1:]
     zeros = jnp.zeros(full_shape, g.dtype)
@@ -157,13 +156,13 @@ def axes_index(axes: Axes):
         return jnp.int32(0)
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * _axis_size(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
 def axes_size(axes: Axes) -> int:
     import math
-    return math.prod(_axis_size(a) for a in axes) if axes else 1
+    return math.prod(lax.axis_size(a) for a in axes) if axes else 1
 
 
 # --------------------------------------------------------------------------
@@ -264,15 +263,13 @@ def vocab_parallel_xent(x, head_local, labels, axes: Axes, *,
     n = t // chunk
     rem = t - n * chunk
 
-    # rank-1 carry: jax 0.4.x shard_map mis-names rank-0 scan-carry
-    # residuals under remat (see core/compat.py) — (1,) sidesteps it.
     @jax.checkpoint
     def step(carry, inp):
         xc, lc, mc = inp
         nll = _xent_chunk(xc, head_local, lc, axes, softcap)
         return carry + jnp.sum(nll * mc), None
 
-    init = jnp.zeros((1,), jnp.float32)
+    init = jnp.zeros((), jnp.float32)
     if n:
         xs = (xf[:n * chunk].reshape(n, chunk, d),
               lf[:n * chunk].reshape(n, chunk),
@@ -282,4 +279,4 @@ def vocab_parallel_xent(x, head_local, labels, axes: Axes, *,
         nll = _xent_chunk(xf[n * chunk:], head_local, lf[n * chunk:], axes,
                           softcap)
         init = init + jnp.sum(nll * mf[n * chunk:])
-    return jnp.sum(init), jnp.sum(mf)
+    return init, jnp.sum(mf)

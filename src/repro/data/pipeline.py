@@ -30,11 +30,17 @@ class DataConfig:
 
 
 def _host_tokens(cfg: DataConfig, step: int, start: int, count: int):
-    """Deterministic tokens for rows [start, start+count) of global batch."""
+    """Deterministic tokens for rows [start, start+count) of global batch.
+
+    Ids over ``[3, vocab)`` follow a Zipf law (rank ``r`` has weight
+    ``1/r``, as word frequencies in text do), so a model has something to
+    learn: on uniform ids no model's loss falls below ``ln(vocab)``."""
     rng = np.random.default_rng(
         np.random.SeedSequence([cfg.seed, step, start]))
-    toks = rng.integers(3, cfg.vocab_size, size=(count, cfg.seq_len + 1),
-                        dtype=np.int32)
+    cdf = np.cumsum(1.0 / np.arange(1, cfg.vocab_size - 2))
+    ranks = np.searchsorted(cdf, rng.random((count, cfg.seq_len + 1))
+                            * cdf[-1])
+    toks = (3 + ranks).astype(np.int32)
     if cfg.pack:
         # synthetic doc boundaries every ~512 tokens
         doc_len = rng.integers(256, 1024)

@@ -52,7 +52,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 from repro.core.tmp import Axes, axes_index, axes_size
 
 # --------------------------------------------------------------------------
@@ -285,7 +284,7 @@ def pallas_tile_matmul(x, w, *, block_m: Optional[int] = None,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)
@@ -417,7 +416,7 @@ def pallas_matmul_reducescatter(x, w, axes: Axes, scatter_dim: int):
             pltpu.SemaphoreType.DMA((2,)),                 # recv
             pltpu.SemaphoreType.REGULAR((2,)),             # consumption ack
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             collective_id=0),
     )(x2, w)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -129,7 +128,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),      # l (running denom)
             pltpu.VMEM((bq, hd), jnp.float32),   # acc
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -239,7 +238,7 @@ def paged_flash_decode(q, k_pages, v_pages, tables, pos, *,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(flat_tables, pos.astype(jnp.int32), qt, k_pages, v_pages)

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_scr, *, nk: int):
@@ -53,7 +52,7 @@ def moe_gmm(x, w, *, block_c: int = 128, block_f: int = 128,
                                lambda ei, ci, fi, ki: (ei, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

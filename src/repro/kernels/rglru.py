@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 C_CONST = 8.0
 
@@ -74,7 +73,7 @@ def rglru(x, params, *, block_t: int = 64, block_w: int = 512,
             out_specs=pl.BlockSpec((1, bt, bw), lambda wi, ti: (0, ti, wi)),
             out_shape=jax.ShapeDtypeStruct((1, s, w), x.dtype),
             scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
         )(xb[None], params["w_a"], params["b_a"], params["w_x"],

@@ -49,7 +49,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 from repro.core.tmp import Axes, axes_index, axes_size
 from repro.kernels.collective_matmul import _ring_perm
 from repro.models.attention import NEG_INF, chunked_attention
@@ -362,7 +361,7 @@ def pallas_ring_forward(q, k, v, axes: Axes, *, causal=True, window=None,
             pltpu.SemaphoreType.DMA((2,)),             # v recv
             pltpu.SemaphoreType.REGULAR((2,)),         # consumption ack
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             collective_id=1),   # distinct from the fused-matmul ring
     )(qs, k, v)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 
 def _kernel(x_ref, dta_ref, dtx_ref, b_ref, c_ref, o_ref, s_scr, *, q: int):
@@ -91,7 +90,7 @@ def ssd(x, dt, A_log, B, C, D, *, chunk: int = 128,
                                lambda bi, hi, ci: (bi, hi, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dtx_t, dta_t, dtx_t, B, C)

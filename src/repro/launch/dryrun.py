@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
-# Only the dry-run sees 512 placeholder devices; tests/benches see 1.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any jax import: jax locks the platform and device count on
+# first init.  Only the dry-run sees 512 placeholder CPU devices (never a
+# chip, also in its sweep children); tests/benches see 1.
 
 import argparse          # noqa: E402
 import json              # noqa: E402

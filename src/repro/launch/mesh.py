@@ -12,16 +12,11 @@ import jax
 from repro.core import compat
 
 
-def _mk(shape, axes):
-    return compat.make_mesh(shape, axes,
-                            axis_types=compat.auto_axis_types(len(axes)))
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_factored_mesh(*, multi_pod: bool = False):
@@ -32,21 +27,21 @@ def make_factored_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 2, 2, 2, 2) if multi_pod else (16, 2, 2, 2, 2)
     axes = (("pod", "data", "t1", "t2", "t3", "t4") if multi_pod
             else ("data", "t1", "t2", "t3", "t4"))
-    return _mk(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_2d_mesh(data: int, dx: int, dy: int):
     """Uniform 2D hybrid-partition mesh ``('data','model_x','model_y')``:
     weight width shards over the dx-way intra-node axis, the contraction
     dim over the dy-way inter-node axis (commodity-server placement)."""
-    return _mk((data, dx, dy), ("data", "model_x", "model_y"))
+    return compat.make_mesh((data, dx, dy), ("data", "model_x", "model_y"))
 
 
 def make_pipeline_mesh(pp: int, data: int, model: int):
     """``('pipe','data','model')`` — pipeline stages outermost (on
     commodity clusters the stage boundaries ride the thin inter-node
     links), TMP innermost on the fast lanes."""
-    return _mk((pp, data, model), ("pipe", "data", "model"))
+    return compat.make_mesh((pp, data, model), ("pipe", "data", "model"))
 
 
 _MESH_HELP = ("expected 'DxM' (data x model, e.g. '32x8') or 'DxMxxMy' "
@@ -91,7 +86,7 @@ def parse_mesh_shape(spec: str, *, pp: int = 0):
     """'dxm' -> 1D ('data','model'); 'dxm1xm2' -> 2D mesh; ``pp > 1``
     prepends a 'pipe' axis (PxDxM)."""
     shape, axes = parse_mesh_spec(spec, pp=pp)
-    return _mk(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def parse_degrees(spec: str):
@@ -140,7 +135,7 @@ def make_smoke_mesh(devices=None):
     """1x1 (or all-local-devices) mesh for CPU smoke tests."""
     n = len(devices or jax.devices())
     d = max(1, n // 4) if n >= 4 else 1
-    return _mk((d, n // d), ("data", "model"))
+    return compat.make_mesh((d, n // d), ("data", "model"))
 
 
 # --------------------------------------------------------------------------
@@ -203,7 +198,7 @@ def resolve_launch(cfg, hp, *, mesh: str = "auto", pp: int = 1,
         plan = ParallelPlan.load(plan_file).validate_for(cfg)
         hp = plan.apply(hp)
         if plan.mesh_shape:
-            m = _mk(plan.mesh_shape, plan.mesh_axes)
+            m = compat.make_mesh(plan.mesh_shape, plan.mesh_axes)
         else:
             m = resolve_mesh_spec(mesh, pp=plan.pp, devices=devices)
         log(f"[plan] loaded {plan_file}: {plan.summary()}")

@@ -37,6 +37,8 @@ import numpy as np
 
 def main():
     from repro.core.schedule import SCHEDULES
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")

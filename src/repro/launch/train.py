@@ -1,12 +1,10 @@
 """Training launcher.
 
-Single-host CPU testbed:
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
         --reduced --steps 50 --batch 8 --seq 128
 
-TPU pod (per-host, via launch/scripts/tpu_pod.sh): the same entrypoint with
---distributed initializes jax.distributed from the TPU environment and
-builds the production mesh.
+``--distributed`` initializes ``jax.distributed`` before the mesh is built.
+Compiled steps persist in JAX's compilation cache (``launch/cache.py``).
 """
 from __future__ import annotations
 
@@ -16,6 +14,8 @@ import json
 
 def main():
     from repro.core.schedule import SCHEDULES
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")

@@ -22,7 +22,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import GLOBAL_ATTN, ArchConfig, TrainHParams
-from repro.core import compat
 from repro.core import tmp as tmpc
 from repro.core.axes import MeshInfo, batch_pspec, mesh_info
 from repro.core.remat import maybe_checkpoint
@@ -90,10 +89,7 @@ def _stack_scan(cfg, ctx, hp, params, xs, auxs, *, train=True):
     if train:
         body = maybe_checkpoint(block_body, remat=hp.remat,
                                 fine=hp.fine_remat)
-    # NOTE: the aux carry is kept rank-1: jax 0.4.x shard_map mis-names
-    # rank-0 scan-carry residuals under the fine-remat policy (see
-    # core/compat.py); a (1,) carry sidesteps it at zero cost.
-    carry = (xs, jnp.zeros((1,), jnp.float32))
+    carry = (xs, jnp.zeros((), jnp.float32))
     if n:
         carry, _ = lax.scan(body, carry, tuple(params["blocks"]))
     xs, aux = carry
@@ -110,7 +106,7 @@ def _stack_scan(cfg, ctx, hp, params, xs, auxs, *, train=True):
             xs, a = apply_layer(parts[kind], params["tail"][i], xs, auxs,
                                 hp.schedule)
             aux = aux + a
-    return xs, jnp.sum(aux)
+    return xs, aux
 
 
 # --------------------------------------------------------------------------
@@ -159,7 +155,7 @@ def _pipeline_scan(cfg, ctx, info: MeshInfo, hp, params, x):
             return (hs_c, a_c), None
 
         body = maybe_checkpoint(body, remat=hp.remat, fine=hp.fine_remat)
-        (hs, aux), _ = lax.scan(body, (hs, jnp.zeros((1,), jnp.float32)),
+        (hs, aux), _ = lax.scan(body, (hs, jnp.zeros((), jnp.float32)),
                                 chunk)
         return merge_tree(hs) if len(hs) > 1 else hs[0], aux
 
@@ -170,7 +166,7 @@ def _pipeline_scan(cfg, ctx, info: MeshInfo, hp, params, x):
     # each layer accumulates its (mean-normalized) aux once per microbatch
     # here but once per pass in the non-PP paths — renormalize so the aux
     # term does not grow with the 1F1B microbatch count
-    return out.reshape((b,) + tuple(x.shape[1:])), jnp.sum(aux) / n_micro
+    return out.reshape((b,) + tuple(x.shape[1:])), aux / n_micro
 
 
 # --------------------------------------------------------------------------
@@ -209,7 +205,7 @@ def _grouped_scan(cfg, info, hp, params, x, degrees, schedules=None,
             cur_axes = new_axes
         return x
 
-    aux_total = jnp.zeros((1,), jnp.float32)   # rank-1: see _stack_scan NOTE
+    aux_total = jnp.zeros((), jnp.float32)
     for g_params, g in zip(params["groups"],
                            prm.plan_groups(cfg, degrees, schedules, seqs)):
         sched = g.schedule if schedules is not None else hp.schedule
@@ -250,7 +246,7 @@ def _grouped_scan(cfg, info, hp, params, x, degrees, schedules=None,
         if g.seq > 1:
             x = tmpc.sp_all_gather(x, ctx.tp_axes, 1)
     x = reshard(x, ())
-    return x, jnp.sum(aux_total)
+    return x, aux_total
 
 
 # --------------------------------------------------------------------------
@@ -440,10 +436,10 @@ def build_train_loss(cfg: ArchConfig, mesh, hp: TrainHParams, *,
         return loss_sum / count + aux, aux
 
     in_specs = (prm.pspec_tree(specs), batch_specs)
-    sm = compat.shard_map(body, mesh=mesh,
-                          in_specs=(in_specs[0],
-                                    {k: v for k, v in batch_specs.items()}),
-                          out_specs=(P(), P()), check_vma=False)
+    sm = jax.shard_map(body, mesh=mesh,
+                       in_specs=(in_specs[0],
+                                 {k: v for k, v in batch_specs.items()}),
+                       out_specs=(P(), P()), check_vma=False)
     return sm, specs, in_specs
 
 
@@ -573,7 +569,7 @@ def build_prefill(cfg: ArchConfig, mesh, hp: TrainHParams, *,
         return greedy_token(logits, ctx.tp_axes), sts
 
     st_out_specs = prm.pspec_tree(st_specs)
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh, in_specs=(prm.pspec_tree(specs), batch_specs),
         out_specs=(bspec, st_out_specs), check_vma=False)
     return sm, specs, st_specs
@@ -665,7 +661,7 @@ def build_decode(cfg: ArchConfig, mesh, hp: TrainHParams, *,
 
     st_ps = prm.pspec_tree(st_specs)
     extra_ps = (P(), P(), P()) if paged is not None else ()
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(prm.pspec_tree(specs), st_ps, bspec, bspec) + extra_ps,
         out_specs=(bspec, st_ps), check_vma=False)
@@ -749,7 +745,7 @@ def _build_decode_pp(cfg, mesh, hp, info, ctx, specs, st_specs, bspec,
 
     st_ps = prm.pspec_tree(st_specs)
     extra_ps = (P(), P(), P()) if paged is not None else ()
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(prm.pspec_tree(specs), st_ps, bspec, bspec) + extra_ps,
         out_specs=(bspec, st_ps), check_vma=False)
@@ -850,7 +846,7 @@ def build_verify(cfg: ArchConfig, mesh, hp: TrainHParams, *,
 
     st_ps = prm.pspec_tree(st_specs)
     extra_ps = (P(), P(), P()) if paged is not None else ()
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(prm.pspec_tree(specs), st_ps, bspec, bspec) + extra_ps,
         out_specs=(bspec, st_ps), check_vma=False)

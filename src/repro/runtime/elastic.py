@@ -358,14 +358,12 @@ def mesh_for(topology: Topology, plan: Optional[ParallelPlan] = None,
             and math.prod(plan.mesh_shape) <= n:
         return compat.make_mesh(
             tuple(plan.mesh_shape), tuple(plan.mesh_axes),
-            axis_types=compat.auto_axis_types(len(plan.mesh_shape)),
             devices=devs[:math.prod(plan.mesh_shape)])
     tp = default_tp or 2 ** int(math.log2(n))
     tp = min(tp, n)
     while n % tp:
         tp //= 2
     return compat.make_mesh((n // tp, tp), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2),
                             devices=devs)
 
 

@@ -233,14 +233,16 @@ class Trainer:
         return psh, osh
 
     def init_state(self, seed: int = 0):
-        params = prm.init_params(self.specs, jax.random.PRNGKey(seed))
-        opt = adamw.init_opt_state(params, self.specs, self.info,
-                                   zero1=self.hp.zero1)
-        psh, osh = self._shardings()
-        params = jax.tree_util.tree_map(jax.device_put, params, psh)
-        opt = jax.tree_util.tree_map(
-            lambda v, s: v if v is None or s is None else jax.device_put(v, s),
-            opt, osh, is_leaf=lambda x: x is None)
+        """Fresh (params, opt) made in place: one jitted init whose outputs
+        land on their shardings, so no device ever holds the whole
+        unsharded state, and every leaf is its own (donatable) buffer."""
+        def init(key):
+            params = prm.init_params(self.specs, key)
+            return params, adamw.init_opt_state(params, self.specs, self.info,
+                                                zero1=self.hp.zero1)
+
+        params, opt = jax.jit(init, out_shardings=self._shardings())(
+            jax.random.PRNGKey(seed))
         return params, opt, 0
 
     def _plan_remap(self, metadata: Dict):

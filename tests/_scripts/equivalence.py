@@ -5,7 +5,6 @@ import runner  # noqa: F401  (must be first: sets XLA_FLAGS before jax)
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
 from repro.configs.base import TrainHParams
 from repro.models import lm
 from repro.models import params as prm
@@ -32,7 +31,7 @@ for sched in ["megatron", "wang", "merak", "oases"]:
     p = prm.init_params(specs, jax.random.PRNGKey(0))
     b = {"tokens": jnp.ones((4, 64), jnp.int32),
          "labels": jnp.ones((4, 64), jnp.int32)}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         losses[sched] = float(jax.jit(fn)(p, b)[0])
 spread = max(losses.values()) - min(losses.values())
 runner.report("schedules", spread < 1e-5, f"spread={spread:.2e}")

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.configs.base import TrainHParams
 from repro.kernels import collective_matmul as cm
 
@@ -35,10 +34,10 @@ def _tol(dtype):
 
 def pair(mesh, fused_body, ref_body, in_specs, out_specs, args):
     """((fused_out, fused_grads), (ref_out, ref_grads)) under shard_map."""
-    smf = compat.shard_map(fused_body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs)
-    smr = compat.shard_map(ref_body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs)
+    smf = jax.shard_map(fused_body, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_specs, check_vma=False)
+    smr = jax.shard_map(ref_body, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_specs, check_vma=False)
 
     def loss(f):
         return lambda *a: sum(
@@ -109,10 +108,10 @@ mesh8 = runner.mesh(8, axes=("model",))
 x30 = jax.random.normal(jax.random.PRNGKey(1), (2, 30, 64))
 w64 = 0.1 * jax.random.normal(jax.random.PRNGKey(2), (64, 48))
 try:
-    sm = compat.shard_map(
+    sm = jax.shard_map(
         lambda xl, wl: cm.fused_matmul_reducescatter(xl, wl, AXES, 1),
         mesh=mesh8, in_specs=(P(None, None, "model"), P("model", None)),
-        out_specs=P(None, "model", None))
+        out_specs=P(None, "model", None), check_vma=False)
     jax.jit(sm)(x30, w64)
     runner.report("rs-nodiv-raises", False, "no error raised")
 except ValueError as e:
@@ -120,7 +119,7 @@ except ValueError as e:
 
 # (c) degree=1 degeneracy: a real size-1 model axis must degrade to the
 # plain dot (backend 'ref'), forward and gradient
-mesh1 = jax.make_mesh((8, 1), ("data", "model"))
+mesh1 = runner.mesh(8, 1)
 xb = jax.random.normal(jax.random.PRNGKey(3), (8, 16, 32))
 wb = 0.1 * jax.random.normal(jax.random.PRNGKey(4), (32, 24))
 f, r = pair(

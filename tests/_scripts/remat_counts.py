@@ -5,7 +5,6 @@ import runner  # noqa: F401  (must be first: sets XLA_FLAGS before jax)
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
 from repro.configs.base import TrainHParams
 from repro.models import lm
 from repro.models import params as prm
@@ -20,7 +19,7 @@ for fine in [False, True]:
     p = prm.init_params(specs, jax.random.PRNGKey(0))
     b = {"tokens": jnp.zeros((4, 64), jnp.int32),
          "labels": jnp.zeros((4, 64), jnp.int32)}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jx = jax.make_jaxpr(jax.grad(lambda p, b: fn(p, b)[0]))(p, b)
     counts[fine] = str(jx).count("psum")
 runner.report("remat-collectives", counts[True] < counts[False],

@@ -19,7 +19,6 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import TrainHParams
@@ -29,7 +28,7 @@ from repro.models.attention import chunked_attention
 # ---------------------------------------------------------------------------
 # kernel tier
 # ---------------------------------------------------------------------------
-kmesh = jax.make_mesh((runner.N_DEVICES,), ("model",))
+kmesh = runner.mesh(runner.N_DEVICES)
 
 
 def kernel_case(name, *, b=2, s=64, h=4, kvh=4, hd=16, causal=True,
@@ -58,11 +57,11 @@ def kernel_case(name, *, b=2, s=64, h=4, kvh=4, hd=16, causal=True,
                               window=window, softcap=softcap,
                               q_positions=qp, kv_positions=kvp)
 
-    smap = shard_map(ring_body, mesh=kmesh,
-                     in_specs=(P(None, "model"), P(None, "model"),
-                               P(None, "model"), P(None, "model"),
-                               P(None, "model")),
-                     out_specs=P(None, "model"), check_rep=False)
+    smap = jax.shard_map(ring_body, mesh=kmesh,
+                         in_specs=(P(None, "model"), P(None, "model"),
+                                   P(None, "model"), P(None, "model"),
+                                   P(None, "model")),
+                         out_specs=P(None, "model"), check_vma=False)
 
     def loss_ring(q, k, v):
         o = smap(q, k, v, pos, pos)

@@ -45,12 +45,12 @@ def mesh(*shape, axes=None):
     if axes is None:
         axes = {1: ("model",), 2: ("data", "model"),
                 3: ("data", "model_x", "model_y")}[len(shape)]
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return compat.make_mesh(shape, axes)
 
 
 def factored_mesh(data=1, t=(2, 2, 2)):
     names = ("data",) + tuple(f"t{i+1}" for i in range(len(t)))
-    return jax.make_mesh((data,) + tuple(t), names)
+    return compat.make_mesh((data,) + tuple(t), names)
 
 
 def reduced_config(arch: str, *, exact_moe: bool = True):
@@ -117,7 +117,7 @@ def train_loss_and_grads(arch_or_cfg, msh, hp: TrainHParams = None, *,
     else:
         p = prm.init_params(specs, jax.random.PRNGKey(seed))
     b = make_batch(cfg, batch, seq, batch_seed)
-    with compat.set_mesh(msh):
+    with jax.set_mesh(msh):
         loss = float(jax.jit(loss_fn)(p, b)[0])
         grads = jax.jit(jax.grad(lambda p, b: loss_fn(p, b)[0]))(p, b)
     return loss, flatten(grads)

@@ -13,10 +13,8 @@ if SRC not in sys.path:
 
 @pytest.fixture(scope="session")
 def smoke_mesh():
-    # compat.make_mesh drops axis_types on jax versions without AxisType
     from repro.core import compat
-    return compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    return compat.make_mesh((1, 1), ("data", "model"))
 
 
 def subprocess_env():
@@ -24,6 +22,27 @@ def subprocess_env():
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.abspath(SRC)
     return env
+
+
+SCRIPTS = os.path.join(os.path.dirname(__file__), "_scripts")
+
+
+def run_script(name, timeout=900):
+    """Run ``tests/_scripts/<name>`` on 8 virtual devices; assert it exits
+    cleanly and reports at least one PASS and no FAIL line; return the
+    PASS/FAIL lines."""
+    import subprocess
+    p = subprocess.run([sys.executable, os.path.join(SCRIPTS, name)],
+                       capture_output=True, text=True, timeout=timeout,
+                       env=subprocess_env())
+    out = p.stdout + p.stderr
+    assert p.returncode == 0, out[-3000:]
+    lines = [ln for ln in p.stdout.splitlines()
+             if ln.startswith(("PASS", "FAIL"))]
+    assert lines, out[-2000:]
+    bad = [ln for ln in lines if ln.startswith("FAIL")]
+    assert not bad, "\n".join(lines)
+    return lines
 
 
 def optional_hypothesis():

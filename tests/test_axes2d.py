@@ -8,7 +8,8 @@ from repro.core.axes import T_AXES, deg_total, deg_xy, mesh_info
 
 
 def _info(*shape_axes):
-    return mesh_info(AbstractMesh(tuple(shape_axes)))
+    names, sizes = zip(*shape_axes)
+    return mesh_info(AbstractMesh(sizes, names))
 
 
 def test_degree_helpers():

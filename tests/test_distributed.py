@@ -7,37 +7,17 @@ in-process tests must keep the real 1-device topology).  Run the tier with
 ``pytest -m multidevice``; the tests are also marked ``slow`` so the
 default fast loop can deselect them.
 """
-import os
-import subprocess
-import sys
-
 import pytest
 
-from conftest import subprocess_env
-
-SCRIPTS = os.path.join(os.path.dirname(__file__), "_scripts")
+from conftest import run_script
 
 multidevice = pytest.mark.multidevice
-
-
-def _run(name, timeout=900):
-    p = subprocess.run([sys.executable, os.path.join(SCRIPTS, name)],
-                       capture_output=True, text=True, timeout=timeout,
-                       env=subprocess_env())
-    out = p.stdout + p.stderr
-    assert p.returncode == 0, out[-3000:]
-    lines = [ln for ln in p.stdout.splitlines()
-             if ln.startswith(("PASS", "FAIL"))]
-    assert lines, out[-2000:]
-    bad = [ln for ln in lines if ln.startswith("FAIL")]
-    assert not bad, "\n".join(lines)
-    return lines
 
 
 @multidevice
 @pytest.mark.slow
 def test_tmp_equivalence_and_schedules():
-    lines = _run("equivalence.py")
+    lines = run_script("equivalence.py")
     assert len(lines) >= 8          # 7 archs + schedule agreement
 
 
@@ -46,7 +26,7 @@ def test_tmp_equivalence_and_schedules():
 def test_2d_hybrid_equivalence():
     """2x2 model-mesh 2D forward+grad vs the single-device oracle, plus
     mixed 1D/2D planner degrees on the factored mesh (PR acceptance)."""
-    lines = _run("equivalence_2d.py", timeout=1800)
+    lines = run_script("equivalence_2d.py", timeout=1800)
     assert len(lines) >= 26         # 7 archs x 3 schedules + plan cases
 
 
@@ -58,26 +38,26 @@ def test_plan_equivalence():
     degrees on a plain mesh, MoE interplay, and mixed (degree, schedule)
     plans on the factored mesh — are loss- AND grad-identical to the
     1-device oracle (PR acceptance)."""
-    lines = _run("plan_equivalence.py", timeout=1800)
+    lines = run_script("plan_equivalence.py", timeout=1800)
     assert len(lines) >= 8
 
 
 @multidevice
 @pytest.mark.slow
 def test_fine_remat_removes_recompute_collectives():
-    _run("remat_counts.py")
+    run_script("remat_counts.py")
 
 
 @multidevice
 @pytest.mark.slow
 def test_fault_tolerant_restart():
-    _run("ft_restart.py")
+    run_script("ft_restart.py")
 
 
 @multidevice
 @pytest.mark.slow
 def test_elastic_remesh_resume():
-    _run("elastic.py")
+    run_script("elastic.py")
 
 
 @multidevice
@@ -88,7 +68,7 @@ def test_elastic_replan():
     against an uninterrupted oracle; a link-bandwidth fault replans
     without chip loss; a corrupted checkpoint shard resumes from the
     previous intact checkpoint."""
-    lines = _run("elastic_replan.py", timeout=1800)
+    lines = run_script("elastic_replan.py", timeout=1800)
     assert len(lines) >= 4
 
 
@@ -100,28 +80,15 @@ def test_telemetry_end_to_end():
     async-checkpoint write latency, the overlap probe's per-layer-group
     measured-vs-modeled exposed-communication events, and the enriched
     per-host heartbeat the straggler localizer consumes."""
-    lines = _run("telemetry_run.py")
+    lines = run_script("telemetry_run.py")
     assert len(lines) >= 8
 
 
 @multidevice
 @pytest.mark.slow
 def test_sequence_parallel_equivalence():
-    lines = _run("sp_equivalence.py")
+    lines = run_script("sp_equivalence.py")
     assert len(lines) >= 5
-
-
-@multidevice
-@pytest.mark.slow
-def test_ring_attention_equivalence():
-    """Ring attention (PR acceptance, DESIGN.md §12): the 8-way KV-ring
-    kernel matches the 1-device oracle forward AND backward (custom-VJP
-    reverse ring) across fp32/bf16 x causal/sliding-window/GQA/softcap
-    and uneven sequence tiles; the stacked and grouped (mixed per-layer
-    seqs) model paths are loss/grad-identical to the unsharded model;
-    unsatisfiable shard factors raise instead of silently degrading."""
-    lines = _run("ring_equivalence.py", timeout=1800)
-    assert len(lines) >= 18
 
 
 @multidevice
@@ -130,7 +97,7 @@ def test_pipeline_equivalence():
     """Interleaved-1F1B PP x TMP vs the single-device oracle: pp in {2,4}
     x tmp in {1,2} x {megatron,oases,fused}, plus virtual stages, a second
     arch family and PP x 2D hybrid (PR acceptance)."""
-    lines = _run("pipeline_equivalence.py", timeout=1800)
+    lines = run_script("pipeline_equivalence.py", timeout=1800)
     assert len(lines) >= 14
 
 
@@ -145,5 +112,5 @@ def test_serving_equivalence():
     stream), prefix reuse with COW, speculative decoding vs the
     undrafted oracle, and the combined paged+prefix+spec path
     (PR acceptance)."""
-    lines = _run("serving_equivalence.py", timeout=1800)
+    lines = run_script("serving_equivalence.py", timeout=1800)
     assert len(lines) >= 30

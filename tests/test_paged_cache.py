@@ -207,8 +207,7 @@ def _mk_paged_engine(**kw):
     from repro.configs.registry import get_config
     from repro.core import compat
     from repro.serving import ServingEngine
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     cfg = get_config("internlm2-1.8b").reduced().replace(dtype="float32")
     eng = ServingEngine(cfg, mesh, **{"slots": 2, "max_seq": 48,
                                       "paged": True, "page_size": 8, **kw})
@@ -279,8 +278,7 @@ def test_paged_prefix_engine_matches_dense_mid_block_divergence():
             from repro.configs.registry import get_config
             from repro.core import compat
             from repro.serving import ServingEngine
-            mesh = compat.make_mesh((1, 1), ("data", "model"),
-                                    axis_types=compat.auto_axis_types(2))
+            mesh = compat.make_mesh((1, 1), ("data", "model"))
             cfg = get_config("internlm2-1.8b").reduced().replace(
                 dtype="float32")
             eng = ServingEngine(cfg, mesh, slots=2, max_seq=48)

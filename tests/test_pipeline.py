@@ -18,7 +18,8 @@ from repro.models import params as prm
 
 
 def _info(*shape_axes):
-    return mesh_info(AbstractMesh(tuple(shape_axes)))
+    names, sizes = zip(*shape_axes)
+    return mesh_info(AbstractMesh(sizes, names))
 
 
 # --------------------------------------------------------------------------

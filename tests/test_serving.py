@@ -10,8 +10,7 @@ from repro.serving import Request, ServingEngine
 @pytest.fixture(scope="module")
 def engine():
     from repro.core import compat
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     cfg = get_config("internlm2-1.8b").reduced().replace(dtype="float32")
     eng = ServingEngine(cfg, mesh, slots=2, max_seq=48)
     eng.load(seed=0)
@@ -30,8 +29,7 @@ def test_more_requests_than_slots(engine):
 
 def test_greedy_determinism():
     from repro.core import compat
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     cfg = get_config("internlm2-1.8b").reduced().replace(dtype="float32")
 
     def decode_once():
@@ -48,8 +46,7 @@ def test_greedy_determinism():
 
 def _mk_engine(**kw):
     from repro.core import compat
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     cfg = get_config("internlm2-1.8b").reduced().replace(dtype="float32")
     eng = ServingEngine(cfg, mesh, **{"slots": 2, "max_seq": 48, **kw})
     eng.load(seed=0)

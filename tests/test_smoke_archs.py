@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import compat
 from repro.configs.base import TrainHParams
 from repro.configs.registry import ASSIGNED, get_config
 from repro.models import lm
@@ -32,7 +31,7 @@ def test_train_step_smoke(arch, smoke_mesh):
                                             global_batch=2, seq_len=32)
     params = prm.init_params(specs, jax.random.PRNGKey(0))
     batch = _batch(cfg)
-    with compat.set_mesh(smoke_mesh):
+    with jax.set_mesh(smoke_mesh):
         (loss, aux), grads = jax.jit(
             jax.value_and_grad(loss_fn, has_aux=True))(params, batch)
     assert loss.shape == ()
@@ -53,7 +52,7 @@ def test_prefill_decode_smoke(arch, smoke_mesh):
                                seq_len=s)
     params = prm.init_params(specs, jax.random.PRNGKey(0))
     batch = {k: v for k, v in _batch(cfg, b, s).items() if k != "labels"}
-    with compat.set_mesh(smoke_mesh):
+    with jax.set_mesh(smoke_mesh):
         tok, state = jax.jit(pf)(params, batch)
         tok2, state2 = jax.jit(df)(params, state, tok,
                                    jnp.full((b,), s - 1, jnp.int32))

@@ -93,8 +93,7 @@ def test_checkpoint_atomicity_no_tmp_left():
 # ---------------- optimizer ----------------
 def _mesh11():
     from repro.core import compat
-    return compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    return compat.make_mesh((1, 1), ("data", "model"))
 
 
 def test_adamw_decreases_quadratic_loss():

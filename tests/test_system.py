@@ -1,7 +1,6 @@
 """End-to-end behaviour tests for the whole system (single-device mesh)."""
 import jax
 
-from repro.core import compat
 from repro.configs.base import TrainHParams
 from repro.configs.registry import get_config
 from repro.launch import steps as steps_mod
@@ -22,7 +21,7 @@ def test_train_step_improves_loss_on_fixed_batch(smoke_mesh):
     batch = {"tokens": jax.random.randint(k, (2, 32), 0, cfg.vocab_size),
              "labels": jax.random.randint(k, (2, 32), 0, cfg.vocab_size)}
     step = jax.jit(fn)
-    with compat.set_mesh(smoke_mesh):
+    with jax.set_mesh(smoke_mesh):
         losses = []
         for _ in range(12):
             params, opt, m = step(params, opt, batch)
@@ -43,7 +42,7 @@ def test_microbatched_step_matches_full_batch(smoke_mesh):
         info = mesh_info(smoke_mesh)
         params = prm.init_params(specs, jax.random.PRNGKey(0))
         opt = adamw.init_opt_state(params, specs, info)
-        with compat.set_mesh(smoke_mesh):
+        with jax.set_mesh(smoke_mesh):
             _, _, m = jax.jit(fn)(params, opt, batch)
         return float(m["loss"])
 
