@@ -119,9 +119,7 @@ def run_measured(points: int = 0, iters: int = 3, telemetry: str = ""):
     """The measured tier: wall-clock AND calibrated-model tokens/s per
     (config x schedule) point.  ``points`` > 0 truncates the grid (the CI
     smoke runs exactly one point end-to-end).  ``telemetry``: directory
-    for a structured JSONL trace of the run — per-point timings plus the
-    overlap-efficiency probe's per-layer-group exposed-communication
-    events against the same calibrated model the ranking gate uses
+    for a structured JSONL trace of the run's per-point timings
     (uploaded as a CI artifact next to ``BENCH_<tag>.json``)."""
     from repro.core.planner import estimate_iteration
     from repro.core.planner.costmodel import HWConfig
@@ -157,18 +155,10 @@ def run_measured(points: int = 0, iters: int = 3, telemetry: str = ""):
             "modeled_tok_s": est["tokens_per_s"],
         })
         if rec is not None:
-            from repro import obs
             rec.observe("bench.measured_s", t, key=key)
             rec.event("bench.point", key=key,
                       measured_ms=round(t * 1e3, 2),
                       modeled_ms=round(est["iter_s"] * 1e3, 2))
-            try:
-                obs.OverlapProbe.for_run(
-                    cfg, shape, hp, hw,
-                    [tmp] * cfg.num_layers).report(t, rec)
-            except Exception as e:
-                rec.event("overlap.error", key=key,
-                          msg=f"[overlap] bench probe failed: {e!r}")
         print(f"# {key}: measured {t*1e3:.0f} ms / modeled "
               f"{est['iter_s']*1e3:.0f} ms", file=sys.stderr, flush=True)
     if rec is not None:
@@ -187,7 +177,7 @@ def main():
                          "warm-up step)")
     ap.add_argument("--telemetry", default="",
                     help="measured tier: JSONL telemetry directory "
-                         "(per-point timings + overlap-probe events)")
+                         "(per-point timings)")
     args = ap.parse_args()
     if args.tier == "measured":
         print(json.dumps(run_measured(points=args.points,

@@ -40,7 +40,7 @@ def main() -> None:
                     help="timed iterations per measured-tier point")
     ap.add_argument("--telemetry", default="", metavar="DIR",
                     help="measured tier: write a structured JSONL trace "
-                         "(per-point timings + overlap-probe events) "
+                         "(per-point timings) "
                          "under DIR — CI uploads it next to BENCH_<tag>")
     args = ap.parse_args()
     root = os.path.join(os.path.dirname(__file__), "..")

@@ -12,6 +12,7 @@ from repro.configs.base import ArchConfig, ShapeConfig, TrainHParams
 from repro.core.axes import batch_pspec, deg_total, mesh_info
 from repro.models import lm
 from repro.models import params as prm
+from repro.obs import phase_scope
 from repro.optim import adamw
 
 
@@ -142,6 +143,9 @@ def build_train_step(cfg: ArchConfig, mesh, hp: TrainHParams, *,
             .astype(jnp.float32), g, g_shardings)
 
     def train_step(params, opt_state, batch):
+        # JAX names the two passes in each op's metadata (``jvp(`` and
+        # ``transpose(``); ``train.grad_accum`` names the gradient glue
+        # around them, and the optimizer scopes its own update
         if hp.microbatch and hp.microbatch > 1 and not pipelined:
             # gradient accumulation: batch arrives pre-shaped
             # [n_micro, B/n, ...] with the batch dim sharded on axis 1, so
@@ -154,20 +158,24 @@ def build_train_step(cfg: ArchConfig, mesh, hp: TrainHParams, *,
                     lambda t: jax.lax.dynamic_index_in_dim(
                         t, i, 0, keepdims=False), batch)
                 (ls, _), g = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
-                return (jax.tree_util.tree_map(
-                    jnp.add, g_acc, _constrain(g)), l_acc + ls)
+                with phase_scope("train.grad_accum"):
+                    return (jax.tree_util.tree_map(
+                        jnp.add, g_acc, _constrain(g)), l_acc + ls)
 
-            zero_g = jax.tree_util.tree_map(
-                lambda w, s: jax.lax.with_sharding_constraint(
-                    jnp.zeros(w.shape, jnp.float32), s),
-                params, g_shardings)
+            with phase_scope("train.grad_accum"):
+                zero_g = jax.tree_util.tree_map(
+                    lambda w, s: jax.lax.with_sharding_constraint(
+                        jnp.zeros(w.shape, jnp.float32), s),
+                    params, g_shardings)
             grads, loss = jax.lax.fori_loop(0, n, micro, (zero_g, 0.0))
-            grads = jax.tree_util.tree_map(lambda g: g / n, grads)
-            loss = loss / n
+            with phase_scope("train.grad_accum"):
+                grads = jax.tree_util.tree_map(lambda g: g / n, grads)
+                loss = loss / n
         else:
             (loss, _), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, batch)
-            grads = _constrain(grads)
+            with phase_scope("train.grad_accum"):
+                grads = _constrain(grads)
         params, opt_state, gnorm = adamw.apply_updates(
             params, grads, opt_state, ocfg, compress=hp.grad_compress,
             zero_shardings=g_shardings,

@@ -108,9 +108,7 @@ def main():
                          "transient OSError (async retry path)")
     ap.add_argument("--telemetry", default="", metavar="DIR",
                     help="write structured telemetry (JSONL) under DIR; "
-                         "render with `python -m repro.obs.report DIR`. "
-                         "Also enables the end-of-run overlap-efficiency "
-                         "probe (measured vs modeled exposed comm)")
+                         "render with `python -m repro.obs.report DIR`")
     ap.add_argument("--telemetry-flush", type=int, default=64,
                     metavar="N",
                     help="JSONL records buffered between file flushes "

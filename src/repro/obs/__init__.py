@@ -7,10 +7,8 @@
   :class:`NullRecorder` disabled mode whose calls cost well under a
   microsecond (the hot paths are instrumented unconditionally);
 * :func:`phase_scope` / :func:`trace_annotation` — schedule-phase spans
-  that surface the TMP gather/compute/reduce chunks in XLA profiles;
-* :class:`OverlapProbe` — the runtime overlap-efficiency probe: measured
-  exposed-communication fraction per layer group, residual against the
-  calibrated cost model, and the ``calibration_stale`` drift signal;
+  that surface the TMP gather/compute/reduce chunks and the optimizer in
+  XLA profiles, and host regions on the profiler's clock;
 * ``python -m repro.obs.report`` — render a run's JSONL into per-phase
   breakdown tables (the reproduction's own Fig. 2).
 """
@@ -23,13 +21,4 @@ __all__ = [
     "Recorder", "NullRecorder", "NULL",
     "configure", "get_recorder", "set_recorder",
     "phase_scope", "trace_annotation",
-    "OverlapProbe", "plan_group_model",
 ]
-
-
-def __getattr__(name):
-    # probe pulls in the cost model; keep the base import light
-    if name in ("OverlapProbe", "plan_group_model"):
-        from repro.obs import probe
-        return getattr(probe, name)
-    raise AttributeError(name)

@@ -29,6 +29,8 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
+from repro.obs.tracing import trace_annotation
+
 KINDS = ("counter", "gauge", "histogram", "event", "span")
 
 # samples kept per histogram for percentile queries (summary() /
@@ -94,9 +96,10 @@ NULL = NullRecorder()
 class _Span:
     """Timing context manager: records a ``span`` with ``dur_s`` on exit
     (perf_counter — monotonic, so an NTP slew mid-span cannot produce a
-    negative duration)."""
+    negative duration), inside a profiler annotation of the same name so
+    the span also shows on a ``jax.profiler`` capture's clock."""
 
-    __slots__ = ("_rec", "name", "tags", "_t0")
+    __slots__ = ("_rec", "name", "tags", "_t0", "_ann")
 
     def __init__(self, rec: "Recorder", name: str, tags: Dict):
         self._rec = rec
@@ -104,12 +107,15 @@ class _Span:
         self.tags = tags
 
     def __enter__(self):
+        self._ann = trace_annotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._rec._emit("span", self.name,
-                        dur_s=time.perf_counter() - self._t0,
+        dur = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        self._rec._emit("span", self.name, dur_s=dur,
                         tags=self.tags or None)
         return False
 

@@ -8,16 +8,13 @@ Sections:
 
 * **phases** — every histogram/span metric: count, mean, p50/p90/p99 and
   the share of total accounted wall time (the per-phase breakdown);
-* **overlap** — the runtime overlap-efficiency probe's per-layer-group
-  events: predicted vs measured exposed-communication fraction and the
-  residual against the calibrated cost model;
 * **serving** — the serving path's own dashboard when ``serving.*``
   metrics are present: throughput, mean/percentile TTFT, prefix-cache hit
   rate, speculative accept rate, page-pool level and admission
   backpressure;
 * **counters / gauges** — run totals and last-seen levels;
-* **events** — the notable trail (faults, replans, calibration_stale,
-  planner decisions), newest last.
+* **events** — the notable trail (faults, replans, planner decisions),
+  newest last.
 """
 from __future__ import annotations
 
@@ -121,7 +118,6 @@ def render(records: List[Dict]) -> str:
     hists: Dict[str, List[float]] = {}
     counters: Dict[str, float] = {}
     gauges: Dict[str, float] = {}
-    overlap_rows: List[Dict] = []
     events: List[Dict] = []
     for r in records:
         kind = r.get("kind")
@@ -135,8 +131,6 @@ def render(records: List[Dict]) -> str:
         elif kind == "gauge":
             gauges[r["name"]] = float(r["value"])
         elif kind == "event":
-            if r["name"] == "overlap.group":
-                overlap_rows.append(r.get("tags") or {})
             events.append(r)
 
     parts: List[str] = []
@@ -153,20 +147,6 @@ def render(records: List[Dict]) -> str:
         parts.append("== per-phase breakdown ==\n" + _table(
             ["phase", "count", "mean", "p50", "p90", "p99", "total",
              "share"], rows))
-    if overlap_rows:
-        rows = []
-        for t in overlap_rows:
-            rows.append([
-                str(t.get("group", "?")), str(t.get("schedule", "?")),
-                str(t.get("layers", "?")),
-                f"{float(t.get('predicted_exposed_frac', 0)):.1%}",
-                f"{float(t.get('measured_exposed_frac', 0)):.1%}",
-                f"{float(t.get('residual', 0)):+.0%}",
-            ])
-        parts.append(
-            "== overlap efficiency (exposed-communication fraction) ==\n"
-            + _table(["group", "schedule", "layers", "predicted",
-                      "measured", "residual"], rows))
     serving_rows = _serving_section(hists, counters, gauges)
     if serving_rows:
         parts.append("== serving ==\n" + _table(["metric", "value"],
@@ -178,11 +158,9 @@ def render(records: List[Dict]) -> str:
         rows = [[n, f"{v:g}"] for n, v in sorted(gauges.items())]
         parts.append("== gauges (last) ==\n" + _table(["gauge", "value"],
                                                       rows))
-    notable = [e for e in events
-               if e["name"] != "overlap.group"]
-    if notable:
+    if events:
         rows = []
-        for e in notable[-20:]:
+        for e in events[-20:]:
             tags = e.get("tags") or {}
             detail = e.get("msg") or " ".join(f"{k}={v}"
                                               for k, v in tags.items())

@@ -16,6 +16,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.axes import MeshInfo
 from repro.models import params as prm
+from repro.obs import phase_scope
 
 
 @dataclass(frozen=True)
@@ -122,7 +123,20 @@ def apply_updates(params, grads, opt_state, cfg: AdamWConfig, *,
     the f32 grads and the m/v/master update run in the ZeRO-sharded layout
     (per-chip 1/dp size) and the master->param cast happens BEFORE the
     gather back to the replicated param layout — without this, XLA
-    materializes three full-size f32 state tensors per chip (§Perf)."""
+    materializes three full-size f32 state tensors per chip (§Perf).
+
+    Every op of the update, the gradient norm and clipping included, is
+    staged under the ``optim.adamw`` scope, so a profile can tell the
+    optimizer's device time from the passes'."""
+    with phase_scope("optim.adamw"):
+        return _apply_updates(params, grads, opt_state, cfg,
+                              compress=compress,
+                              zero_shardings=zero_shardings,
+                              param_shardings=param_shardings)
+
+
+def _apply_updates(params, grads, opt_state, cfg: AdamWConfig, *,
+                   compress: bool, zero_shardings, param_shardings):
     step = opt_state["step"] + 1
     lr = lr_at(cfg, step)
 

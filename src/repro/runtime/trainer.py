@@ -381,37 +381,6 @@ class Trainer:
             json.dump(hb, f)
         os.replace(tmp, path)
 
-    def _overlap_report(self, step: int):
-        """End-of-run overlap-efficiency probe (repro.obs.probe): decompose
-        the median measured step time against the calibrated cost model's
-        per-layer-group prediction and emit overlap.group / residual /
-        calibration_stale telemetry.  Only runs when the recorder has a
-        JSONL sink (--telemetry) — the probe calls calibrated_hw, which
-        micro-benches this host on a cache miss, a cost the default
-        in-memory recorder must never pay."""
-        if getattr(self.rec, "out_dir", None) is None:
-            return
-        h = getattr(self.rec, "hists", {}).get("trainer.step_time_s")
-        if not h or len(h) < 2:
-            return
-        xs = sorted(list(h)[1:])        # drop the compile step
-        med = xs[len(xs) // 2]
-        try:
-            from repro.core.planner.calibrate import calibrated_hw, describe
-            from repro.core.planner.costmodel import ShapeConfig
-            hw = calibrated_hw(n_chips=max(int(self.mesh.devices.size), 1))
-            degrees = [self.info.tp if d is None else d
-                       for d in self.plan.degrees]
-            probe = obs.OverlapProbe.for_run(
-                self.cfg, ShapeConfig("probe", self.seq_len,
-                                      self.global_batch, "train"),
-                self.hp, hw, degrees, list(self.plan.schedules),
-                hw_note=describe(hw))
-            probe.report(med, self.rec, step=step)
-        except Exception as e:   # the probe must never kill a finished run
-            self.rec.event("overlap.error",
-                           msg=f"[overlap] probe failed: {e!r}")
-
     # ---- main loop ----
     def train(self, total_steps: int, *, ckpt_every: int = 50,
               seed: int = 0, state: Optional[Tuple] = None) -> Dict:
@@ -447,7 +416,7 @@ class Trainer:
                 # mid-step reads as a phantom straggler
                 t0 = time.perf_counter()
                 self.injector.check(step)
-                with obs.trace_annotation("train_step"):
+                with obs.trace_annotation("train.step"):
                     params, opt, metrics = self.step_fn(params, opt, batch)
                     loss = float(metrics["loss"])
                 dt = time.perf_counter() - t0
@@ -489,7 +458,6 @@ class Trainer:
                         "trainer.step", step=step,
                         msg=f"[trainer] step {step} loss {loss:.4f} "
                             f"{dt*1e3:.0f} ms")
-            self._overlap_report(step)
         finally:
             data.close()
             try:

@@ -63,11 +63,19 @@ from repro.serving.paged_cache import PagedKVCache
 
 @dataclass
 class Request:
+    """One generation request.  The engine stamps ``time.perf_counter()``
+    at :meth:`ServingEngine.submit` (``t_submit``), at admission to a slot
+    (``t_admit``) and when the first output token is appended
+    (``t_first``): queue wait is ``t_admit - t_submit``, the prompt path
+    ``t_first - t_admit``."""
     rid: int
     prompt: np.ndarray              # [prompt_len] int32
     max_new_tokens: int = 16
     out_tokens: List[int] = field(default_factory=list)
     done: bool = False
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
 
 class ServingEngine:
@@ -237,7 +245,7 @@ class ServingEngine:
         self.stats = {"decoded_tokens": 0, "steps": 0, "admitted": 0,
                       "prompt_tokens": 0, "prefix_hits": 0,
                       "prefix_hit_tokens": 0, "spec_proposed": 0,
-                      "spec_accepted": 0}
+                      "spec_accepted": 0, "slot_ticks": 0}
         # None -> resolve the process-global recorder per tick, so
         # serve.py's --telemetry (obs.configure) reaches a pre-built engine
         self._telemetry = telemetry
@@ -273,7 +281,7 @@ class ServingEngine:
                 f"exceeds prefill_len={self.prefill_len} (engine admission "
                 f"contract; raise --prefill-len / max_seq or chunk the "
                 f"prompt)")
-        req._submit_t = time.perf_counter()   # TTFT clock starts here
+        req.t_submit = time.perf_counter()   # TTFT clock starts here
         self.queue.put(req)
 
     def _next_request(self) -> Optional[Request]:
@@ -316,6 +324,7 @@ class ServingEngine:
                     self.stats["prefix_hits"] += 1
                     self.stats["prefix_hit_tokens"] += hit
             self.active[s] = req
+            req.t_admit = time.perf_counter()
             self.pos[s] = hit
             self.cur_tok[s] = int(req.prompt[hit])
             self.prev_tok[s] = int(req.prompt[max(hit - 1, 0)])
@@ -383,18 +392,26 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def step(self):
         """One engine iteration: admit, then decode one token for all
-        slots (or one speculative round of up to ``spec_k + 1``)."""
+        slots (or one speculative round of up to ``spec_k + 1``).
+
+        Each phase of the tick is a profiler annotation (``serve.admit``,
+        ``serve.prepare``, ``serve.dispatch``, ``serve.sync``,
+        ``serve.emit``; ``serve.spec_draft`` and ``serve.spec_verify`` for
+        a speculative round), so a trace can say what the host was doing
+        whenever the chip waits."""
         rec = self.rec
-        self._admit()
-        rec.gauge("serving.queue_depth", self.queued)
-        rec.gauge("serving.slot_occupancy",
-                  sum(a is not None for a in self.active) / self.slots)
-        if self.paged is not None:
-            rec.gauge("serving.free_pages", self.paged.free_pages)
-            if self.paged.prefix_enabled and self.stats["prompt_tokens"]:
-                rec.gauge("serving.prefix_hit_rate",
-                          self.stats["prefix_hit_tokens"]
-                          / self.stats["prompt_tokens"])
+        with obs.trace_annotation("serve.admit"):
+            self._admit()
+            active = sum(a is not None for a in self.active)
+            self.stats["slot_ticks"] += active
+            rec.gauge("serving.queue_depth", self.queued)
+            rec.gauge("serving.slot_occupancy", active / self.slots)
+            if self.paged is not None:
+                rec.gauge("serving.free_pages", self.paged.free_pages)
+                if self.paged.prefix_enabled and self.stats["prompt_tokens"]:
+                    rec.gauge("serving.prefix_hit_rate",
+                              self.stats["prefix_hit_tokens"]
+                              / self.stats["prompt_tokens"])
         if self.spec_k:
             if self.stats["spec_proposed"]:
                 rec.gauge("serving.spec_accept_rate",
@@ -406,23 +423,35 @@ class ServingEngine:
 
     def _plain_step(self, rec):
         t0 = time.perf_counter()
-        tokens = jnp.asarray(self.cur_tok)
-        pos = jnp.asarray(self.pos)
-        extra = ()
-        if self.paged is not None:
-            cow: List[Tuple[int, int]] = []
-            for s in range(self.slots):
-                if self.active[s] is not None:
-                    cow += self.paged.ensure_writable(
-                        s, int(self.pos[s]), int(self.pos[s]))
-            extra = self._paged_args(cow)
-        with obs.trace_annotation("engine_tick"):
+        with obs.trace_annotation("serve.prepare"):
+            tokens = jnp.asarray(self.cur_tok)
+            pos = jnp.asarray(self.pos)
+            extra = ()
+            if self.paged is not None:
+                cow: List[Tuple[int, int]] = []
+                for s in range(self.slots):
+                    if self.active[s] is not None:
+                        cow += self.paged.ensure_writable(
+                            s, int(self.pos[s]), int(self.pos[s]))
+                extra = self._paged_args(cow)
+        with obs.trace_annotation("serve.dispatch"):
             next_tok, self.state = self.decode_fn(self.params, self.state,
                                                   tokens, pos, *extra)
+        with obs.trace_annotation("serve.sync"):
             next_tok = np.asarray(jax.device_get(next_tok))
         now = time.perf_counter()
         rec.observe("serving.decode_step_s", now - t0)
         self.stats["steps"] += 1
+        with obs.trace_annotation("serve.emit"):
+            self._emit_plain(rec, next_tok, now)
+
+    def _first_token(self, rec, req: Request, now: float):
+        req.t_first = now
+        if req.t_submit is not None:
+            rec.observe("serving.ttft_s", req.t_first - req.t_submit,
+                        rid=req.rid)
+
+    def _emit_plain(self, rec, next_tok: np.ndarray, now: float):
         decoded = 0
         for s in range(self.slots):
             req = self.active[s]
@@ -437,9 +466,8 @@ class ServingEngine:
                 req._prompt_cursor = cur + 1
                 continue
             tok = int(next_tok[s])
-            if not req.out_tokens and hasattr(req, "_submit_t"):
-                rec.observe("serving.ttft_s", now - req._submit_t,
-                            rid=req.rid)
+            if not req.out_tokens:
+                self._first_token(rec, req, now)
             req.out_tokens.append(tok)
             self.stats["decoded_tokens"] += 1
             decoded += 1
@@ -475,7 +503,7 @@ class ServingEngine:
                 return int(req.prompt[p])
             return None
 
-        with obs.trace_annotation("spec_draft"):
+        with obs.trace_annotation("serve.spec_draft"):
             # catch-up: re-consume prev_tok at pos-1 so the draft cache
             # row the last rejection left stale is repaired before the
             # draft attends through it; its output (a prediction for the
@@ -503,7 +531,7 @@ class ServingEngine:
                     cow += self.paged.ensure_writable(
                         s, int(pos0[s]), int(pos0[s]) + k)
             extra = self._paged_args(cow)
-        with obs.trace_annotation("spec_verify"):
+        with obs.trace_annotation("serve.spec_verify"):
             choices, self.state = self.verify_fn(
                 self.params, self.state, jnp.asarray(tok_block),
                 jnp.asarray(pos0), *extra)
@@ -534,9 +562,8 @@ class ServingEngine:
                     req._prompt_cursor = nxt_pos + 1
                 else:
                     nxt_tok = int(choices[s, j])
-                    if not req.out_tokens and hasattr(req, "_submit_t"):
-                        rec.observe("serving.ttft_s", now - req._submit_t,
-                                    rid=req.rid)
+                    if not req.out_tokens:
+                        self._first_token(rec, req, now)
                     req.out_tokens.append(nxt_tok)
                     self.stats["decoded_tokens"] += 1
                     decoded += 1

@@ -1,12 +1,16 @@
 """Subprocess body: telemetry end-to-end on a real TMP mesh — a short
 training run with a JSONL sink must produce a schema-valid trace carrying
-step-time histograms, per-host heartbeat metrics, and the overlap probe's
-per-layer-group events (measured vs modeled exposed communication)."""
+step-time histograms and per-host heartbeat metrics, and each step must
+show on a profiler capture as a ``train.step`` host span."""
 import runner  # noqa: F401  (must be first: sets XLA_FLAGS before jax)
 
+import glob
 import json
 import os
 import tempfile
+
+import jax
+from jax.profiler import ProfileData
 
 from repro import obs
 from repro.configs.base import TrainHParams
@@ -26,7 +30,12 @@ trainer = Trainer(cfg, mesh,
                                warmup_steps=2, learning_rate=1e-3),
                   global_batch=8, seq_len=64, ckpt_dir=ckpt,
                   telemetry=rec, host_id=1)
-res = trainer.train(8, ckpt_every=4)
+prof = tempfile.mkdtemp()
+jax.profiler.start_trace(prof)
+try:
+    res = trainer.train(8, ckpt_every=4)
+finally:
+    jax.profiler.stop_trace()
 rec.close()
 
 # ---- schema-valid JSONL trace --------------------------------------------
@@ -54,19 +63,13 @@ runner.report("telemetry-console",
               any("loss" in ln for ln in logs),
               f"{len(logs)} console lines preserved")
 
-# ---- overlap probe (the PR acceptance signal) ----------------------------
-groups = [r for r in recs if r["name"] == "overlap.group"]
-ok = bool(groups)
-for g in groups:
-    t = g.get("tags", {})
-    ok = ok and 0.0 <= t.get("measured_exposed_frac", -1) <= 1.0 \
-        and t.get("schedule") == "oases"
-runner.report("telemetry-overlap-groups", ok,
-              f"{len(groups)} layer-group events, schedule tags intact")
-runner.report("telemetry-overlap-gauges",
-              "overlap.measured_exposed_frac" in names
-              and "overlap.model_residual" in names,
-              "overall exposed fraction + model residual gauges present")
+# ---- host spans on the profiler's clock --------------------------------
+pb = glob.glob(os.path.join(prof, "**", "*.xplane.pb"), recursive=True)
+spans = [e for p in ProfileData.from_file(pb[0]).planes
+         if p.name.startswith("/host:") for ln in p.lines
+         for e in ln.events if e.name == "train.step"] if pb else []
+runner.report("telemetry-step-spans", len(spans) == 8,
+              f"{len(spans)} train.step spans in the profile")
 
 # ---- enriched heartbeat (straggler localization input) -------------------
 hb = el.read_heartbeat(el.heartbeat_path(ckpt))

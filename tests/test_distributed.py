@@ -77,11 +77,11 @@ def test_elastic_replan():
 def test_telemetry_end_to_end():
     """Telemetry tier (PR acceptance): a short TMP training run with a
     JSONL sink yields a schema-valid trace with step-time histograms,
-    async-checkpoint write latency, the overlap probe's per-layer-group
-    measured-vs-modeled exposed-communication events, and the enriched
-    per-host heartbeat the straggler localizer consumes."""
+    async-checkpoint write latency, one ``train.step`` profiler span per
+    step, and the enriched per-host heartbeat the straggler localizer
+    consumes."""
     lines = run_script("telemetry_run.py")
-    assert len(lines) >= 8
+    assert len(lines) >= 7
 
 
 @multidevice

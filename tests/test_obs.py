@@ -1,10 +1,9 @@
 """Telemetry subsystem unit tier (src/repro/obs/).
 
-Covers the PR-8 acceptance list: JSONL schema round-trip, ring-buffer
-eviction, histogram percentiles, the disabled-mode overhead guard, the
-overlap-probe residual math on synthetic group models, straggler
-localization from enriched peer heartbeats, and the schedule-phase
-named scopes surviving into compiled HLO.
+JSONL schema round-trip, ring-buffer eviction, histogram percentiles,
+the disabled-mode overhead guard, straggler localization from enriched
+peer heartbeats, the schedule-phase named scopes surviving into compiled
+HLO, and recorder spans landing on the profiler's clock.
 """
 import json
 import os
@@ -141,74 +140,6 @@ def test_null_recorder_overhead():
 
 
 # --------------------------------------------------------------------------
-# overlap-probe residual math (synthetic group models — no jax needed)
-# --------------------------------------------------------------------------
-def _groups():
-    from repro.obs.probe import GroupModel
-    g1 = GroupModel(label="g0:attn[4/oases]x2", kind="attn",
-                    schedule="oases", degree=4, layers=2,
-                    compute_s=0.08, comm_s=0.02, predicted_s=0.09)
-    g2 = GroupModel(label="g1:mlp[4/megatron]x2", kind="mlp",
-                    schedule="megatron", degree=4, layers=2,
-                    compute_s=0.02, comm_s=0.02, predicted_s=0.04)
-    return [g1, g2]
-
-
-def test_probe_predicted_fractions():
-    g1, g2 = _groups()
-    assert abs(g1.predicted_exposed_s - 0.01) < 1e-12
-    assert abs(g1.predicted_exposed_frac - 0.5) < 1e-12
-    assert abs(g2.predicted_exposed_frac - 1.0) < 1e-12
-
-
-def test_probe_residual_math():
-    from repro.obs.probe import OverlapProbe
-    probe = OverlapProbe(_groups())
-    # totals: compute 0.10, comm 0.04, modeled 0.13
-    out = probe.report(0.12)
-    # exposed = 0.12 - 0.10 = 0.02, split by equal comm share
-    assert abs(out["measured_exposed_frac"] - 0.5) < 1e-9
-    r1, r2 = out["groups"]
-    assert abs(r1["measured_exposed_frac"] - 0.5) < 1e-9
-    assert abs(r1["residual"] - 0.0) < 1e-9        # 0.08+0.01 vs 0.09
-    assert abs(r2["residual"] - (-0.25)) < 1e-9    # 0.02+0.01 vs 0.04
-    assert not out["calibration_stale"]            # (0.12-0.13)/0.13 ~ -8%
-
-
-def test_probe_clamps_exposed():
-    from repro.obs.probe import OverlapProbe
-    probe = OverlapProbe(_groups())
-    below = probe.report(0.05)         # under the compute floor
-    assert below["measured_exposed_frac"] == 0.0
-    above = probe.report(1.0)          # way over compute + comm
-    assert above["measured_exposed_frac"] == 1.0   # clamped to comm total
-    assert above["calibration_stale"]
-
-
-def test_probe_emits_stale_event_through_recorder():
-    from repro.obs.probe import OverlapProbe
-    r = Recorder()
-    OverlapProbe(_groups()).report(1.0, r, step=7)
-    names = [rec["name"] for rec in r.ring]
-    assert names.count("overlap.group") == 2
-    assert "calibration_stale" in names
-    assert abs(r.gauges["overlap.measured_exposed_frac"] - 1.0) < 1e-9
-    stale = [rec for rec in r.ring if rec["name"] == "calibration_stale"][0]
-    assert "re-run calibration" in stale["msg"]
-    assert stale["tags"]["step"] == 7
-
-
-def test_probe_skips_without_comm():
-    from repro.obs.probe import GroupModel, OverlapProbe
-    g = GroupModel(label="g0", kind="attn", schedule="oases", degree=1,
-                   layers=2, compute_s=0.1, comm_s=0.0, predicted_s=0.1)
-    r = Recorder()
-    out = OverlapProbe([g]).report(0.2, r)
-    assert out["skipped"] == "no-comm"
-    assert r.ring[-1]["name"] == "overlap.skip"
-
-
-# --------------------------------------------------------------------------
 # straggler localization from enriched peer heartbeats
 # --------------------------------------------------------------------------
 def _write_hb(path, host, ewma):
@@ -270,10 +201,6 @@ def test_report_render_and_validate(tmp_path, capsys):
             r.observe("trainer.step_time_s", 0.01 * (i + 1), step=i)
         r.counter("serving.decoded_tokens", 64)
         r.gauge("serving.queue_depth", 2)
-        r.event("overlap.group", group="g0:attn[4/oases]x2",
-                schedule="oases", layers=2,
-                predicted_exposed_frac=0.5, measured_exposed_frac=0.25,
-                residual=-0.1)
         r.event("trainer.restore", msg="[trainer] restored step 5")
     from repro.obs import report
     assert report.main([d, "--validate"]) == 0
@@ -283,7 +210,7 @@ def test_report_render_and_validate(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "per-phase breakdown" in out
     assert "trainer.step_time_s" in out
-    assert "overlap efficiency" in out and "g0:attn[4/oases]x2" in out
+    assert "== events" in out and "restored step 5" in out
 
 
 def test_report_validate_catches_corruption(tmp_path, capsys):
@@ -318,6 +245,36 @@ def test_trace_annotation_is_reentrant():
     with trace_annotation("outer"):
         with trace_annotation("inner"):
             pass
+
+
+def _host_events(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+    f = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True), key=os.path.getmtime)
+    return [(e.name, e.start_ns, e.duration_ns)
+            for p in ProfileData.from_file(f).planes
+            if p.name.startswith("/host:") for ln in p.lines
+            for e in ln.events]
+
+
+def test_recorder_span_on_profiler_clock(tmp_path):
+    """A recorder span is also a profiler annotation of its name, as long
+    as the span it records."""
+    import jax
+    r = Recorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with r.span("train.obs_test_span", layer=1):
+            time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    ev = [e for e in _host_events(str(tmp_path))
+          if e[0] == "train.obs_test_span"]
+    assert len(ev) == 1
+    rec = [x for x in r.ring if x["kind"] == "span"][0]
+    assert rec["dur_s"] == pytest.approx(ev[0][2] * 1e-9, abs=2e-3)
 
 
 # --------------------------------------------------------------------------

@@ -151,3 +151,56 @@ def test_donate_argnums_backend_branch(monkeypatch):
         return r.out_tokens
 
     assert run(eng_cpu) == run(eng_tpu)
+
+
+def test_request_stamps_and_queue_wait():
+    """The engine stamps every request at submit, admission and first
+    token, in that order; with more requests than slots the late ones wait
+    in the queue, and ``slot_ticks`` sums the active slots over ticks."""
+    from repro.obs import Recorder
+    eng = _mk_engine(telemetry=Recorder(), eos_id=-1)
+    reqs = [Request(rid=i, prompt=np.arange(3, 6, dtype=np.int32),
+                    max_new_tokens=3) for i in range(4)]
+    for r in reqs:
+        eng.submit(r)
+    ticks = 0
+    while eng.queued or any(a is not None for a in eng.active):
+        eng.step()
+        ticks += 1
+    for r in reqs:
+        assert r.done
+        assert r.t_submit <= r.t_admit <= r.t_first
+    waits = [r.t_admit - r.t_submit for r in reqs]
+    assert min(waits[2:]) > max(waits[:2]) > 0
+    # 3 prompt tokens then 3 outputs: each request holds a slot 5 ticks
+    assert eng.stats["slot_ticks"] == 4 * 5
+    assert eng.stats["steps"] == ticks
+    ttft = list(eng.rec.hists["serving.ttft_s"])[-4:]
+    assert ttft == [r.t_first - r.t_submit for r in reqs]
+
+
+def test_tick_phases_on_profiler_trace(tmp_path):
+    """One tick under ``jax.profiler`` shows each of the engine's phases as
+    a host span, in order, and no other engine span."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    eng = _mk_engine()
+    eng.submit(Request(rid=0, prompt=np.arange(3, 6, dtype=np.int32),
+                       max_new_tokens=2))
+    eng.step()                      # compiles
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    f = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                  recursive=True)[0]
+    spans = sorted((e.start_ns, e.name)
+                   for p in ProfileData.from_file(f).planes
+                   if p.name.startswith("/host:") for ln in p.lines
+                   for e in ln.events if e.name.startswith("serve."))
+    assert [n for _, n in spans] == ["serve.admit", "serve.prepare",
+                                     "serve.dispatch", "serve.sync",
+                                     "serve.emit"]
