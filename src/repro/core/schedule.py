@@ -1,8 +1,17 @@
 """The four TMP training schedules (paper §3, Fig. 3, Alg. 1–2).
 
-All schedules are expressed as *program structure*; on TPU the XLA
-latency-hiding scheduler turns the admitted independence into actual
-comm/compute overlap (DESIGN.md §2):
+All schedules are expressed as *program structure*.  Independence alone
+does not make the TPU compiler overlap anything: its all-reduce combiner
+merges independent sub-batch reductions into one, and by default an
+all-reduce runs synchronously, holding the TensorCore.  So the split
+schedules also fix Alg. 1's order: ``apply_layer`` ties each reduction to
+the output of the one emitted before it with an ``optimization_barrier``
+on the partial product, which keeps the reductions apart and leaves the
+matmul that makes it free to run under the previous reduction.  On TPU the
+train step compiles its TMP reductions asynchronously
+(:func:`repro.launch.steps.jit_train_step`), and the
+latency-hiding scheduler places each under the next sub-batch's compute
+(DESIGN.md §2):
 
 * ``megatron`` — Fig. 3a: one batch, strictly sequential blocked AllReduce.
 * ``wang``     — Wang et al. [53]: decompose each row-parallel matmul into
@@ -22,17 +31,61 @@ comm/compute overlap (DESIGN.md §2):
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core import tmp as tmpc
 from repro.core.axes import MeshInfo
 from repro.obs.tracing import phase_scope
 
 SCHEDULES = ("megatron", "wang", "merak", "oases", "fused")
+
+
+class _ReduceOrder:
+    """Alg. 1's order of one layer's TMP reductions, kept at trace time:
+    each reduction waits for the one issued before it."""
+
+    def __init__(self):
+        self.last = None
+
+    def reduce(self, fn: Callable, x):
+        if self.last is not None:
+            # only the partial product waits, not the matmul that made it:
+            # that matmul is the window the previous reduction hides under,
+            # and the combiner can no longer merge the two reductions.  The
+            # barrier transposes to a barrier on the cotangents, so the
+            # backward reductions get the mirrored order.
+            _, x = lax.optimization_barrier((self.last, x))
+        self.last = fn(x)
+        return self.last
+
+
+_REDUCE_ORDER: contextvars.ContextVar = contextvars.ContextVar(
+    "tmp_reduce_order", default=None)
+
+
+@contextlib.contextmanager
+def _reduce_order(split: int):
+    """Order the TMP reductions of the layer traced inside the block when
+    its batch is split into ``split`` > 1 sub-batches."""
+    token = _REDUCE_ORDER.set(_ReduceOrder() if split > 1 else None)
+    try:
+        yield
+    finally:
+        _REDUCE_ORDER.reset(token)
+
+
+def _in_order(fn: Callable, x, axes):
+    """``fn(x)`` — a TMP reduction over ``axes`` — in the order of the
+    layer being traced, if any and if ``axes`` reduce anything."""
+    order = _REDUCE_ORDER.get()
+    return fn(x) if order is None or not axes else order.reduce(fn, x)
 
 
 @dataclass(frozen=True)
@@ -115,9 +168,11 @@ class TmpCtx:
     def reduce(self, x, seq_dim: int = 1):
         if self.seq_parallel and self.tp_axes:
             from jax.ad_checkpoint import checkpoint_name
-            y = tmpc.sp_reduce_scatter(x, self.tp_axes, seq_dim)
-            return checkpoint_name(y, tmpc.COLLECTIVE_NAME)
-        return tmpc.tmp_reduce(x, self.tp_axes)
+            return _in_order(lambda t: checkpoint_name(
+                tmpc.sp_reduce_scatter(t, self.tp_axes, seq_dim),
+                tmpc.COLLECTIVE_NAME), x, self.tp_axes)
+        return _in_order(lambda t: tmpc.tmp_reduce(t, self.tp_axes), x,
+                         self.tp_axes)
 
     def gather_seq(self, x, seq_dim: int = 1):
         """Block entry in SP mode: reassemble the full sequence."""
@@ -224,7 +279,9 @@ class TmpCtx:
                         use_pallas=self.use_pallas)
                     y = checkpoint_name(y, tmpc.COLLECTIVE_NAME)
                 else:
-                    y = tmpc.tmp_reduce(jnp.dot(x, w), self.x_axes)
+                    y = _in_order(
+                        lambda t: tmpc.tmp_reduce(t, self.x_axes),
+                        jnp.dot(x, w), self.x_axes)
                 if full_out is not None and w.shape[-1] != full_out:
                     y = checkpoint_name(
                         tmpc.sp_all_gather(y, self.y_axes, y.ndim - 1),
@@ -312,19 +369,23 @@ def apply_layer(parts: Sequence[Callable], p, xs: List, auxs: List,
 
     Program order = Alg. 1: for each part, emit (compute_j, collective_j) for
     every sub-batch j before the residual adds, so collective_j is independent
-    of compute_{j+1} — the overlap window.  Returns (xs, aux_scalar).
+    of compute_{j+1} — the overlap window.  With more than one sub-batch,
+    each collective is also tied to the output of the one emitted before it
+    (:class:`_ReduceOrder`), so all stay separate reductions in that order.
+    Returns (xs, aux_scalar).
     """
     aux_total = jnp.float32(0.0)
-    for part in parts:
-        deltas = []
-        for j, (x, a) in enumerate(zip(xs, auxs)):
-            # sub-batch scope: Alg. 1's (compute_j, collective_j) chunks
-            # are attributable per sub-batch in XLA profiles
-            with phase_scope(f"tmp.{schedule}.sub{j}"):
-                d, aux = part(p, x, a)
-            deltas.append(d)
-            aux_total = aux_total + aux
-        xs = [x + d for x, d in zip(xs, deltas)]
+    with _reduce_order(len(xs)):
+        for part in parts:
+            deltas = []
+            for j, (x, a) in enumerate(zip(xs, auxs)):
+                # sub-batch scope: Alg. 1's (compute_j, collective_j) chunks
+                # are attributable per sub-batch in XLA profiles
+                with phase_scope(f"tmp.{schedule}.sub{j}"):
+                    d, aux = part(p, x, a)
+                deltas.append(d)
+                aux_total = aux_total + aux
+            xs = [x + d for x, d in zip(xs, deltas)]
     if schedule == "merak":
         xs = [tmpc.pass_barrier(x) for x in xs]
     return xs, aux_total

@@ -19,7 +19,8 @@ from repro.configs.registry import ASSIGNED, get_config, get_shape      # noqa: 
 from repro.core.axes import mesh_info                                   # noqa: E402
 from repro.launch import hlo_cost                                       # noqa: E402
 from repro.launch.mesh import make_factored_mesh, make_production_mesh  # noqa: E402
-from repro.launch.steps import input_specs, step_fn_for                 # noqa: E402
+from repro.launch.steps import (input_specs, jit_train_step,           # noqa: E402
+                                step_fn_for)
 
 # TPU v5e chip constants (roofline targets; this container only compiles)
 PEAK_FLOPS = 197e12          # bf16
@@ -142,8 +143,10 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     # donate params+opt (train) / kv-cache (decode): buffers alias in place
     donate = (0, 1) if shape.kind == "train" else \
         ((1,) if shape.kind == "decode" else ())
+    jitted = (jit_train_step(fn, mesh, donate) if shape.kind == "train"
+              else jax.jit(fn, donate_argnums=donate))
     with mesh:
-        lowered = jax.jit(fn, donate_argnums=donate).lower(*inputs)
+        lowered = jitted.lower(*inputs)
         t_lower = time.perf_counter() - t0
         compiled = lowered.compile()
         t_compile = time.perf_counter() - t0 - t_lower

@@ -214,7 +214,7 @@ class Trainer:
         # rendezvous (execution only — the dry-run donates at compile time);
         # enable it on real accelerators.
         donate = (0, 1) if jax.default_backend() != "cpu" else ()
-        self.step_fn = jax.jit(self.step_fn, donate_argnums=donate)
+        self.step_fn = steps_mod.jit_train_step(self.step_fn, mesh, donate)
         self.info = info
 
     # ---- state ----

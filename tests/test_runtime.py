@@ -433,3 +433,10 @@ def test_fault_event_roundtrip_through_errors():
         el.LinkDegradedError)
     generic = el.fault_from_event(el.FaultEvent("heartbeat-stale", host=1))
     assert type(generic) is el.FaultError
+
+
+def test_train_step_compiler_options_need_tpu_and_model_group(smoke_mesh):
+    """Async all-reduce options follow from the platform and the mesh:
+    a CPU mesh compiles the train step with none."""
+    from repro.launch import steps
+    assert steps.train_compiler_options(smoke_mesh) is None

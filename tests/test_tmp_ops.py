@@ -3,6 +3,7 @@ collective axes are empty tuples, which must degrade to identity)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from conftest import optional_hypothesis
 
@@ -75,3 +76,94 @@ def test_rms_norm_unit_output():
     y = tmpc.rms_norm(x, jnp.zeros((64,)))
     ms = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1)
     np.testing.assert_allclose(ms, jnp.ones_like(ms), rtol=1e-3)
+
+
+# --------------------------------------------------------------------------
+# Alg. 1's reduction order (core/schedule.py): traced over an abstract mesh,
+# so the 2-way model group needs no second device
+# --------------------------------------------------------------------------
+def _forward_barriers(model: int, schedule: str) -> int:
+    from jax.sharding import AbstractMesh
+
+    from repro.configs.base import TrainHParams
+    from repro.configs.registry import get_config
+    from repro.models import lm
+    from repro.models import params as prm
+
+    cfg = get_config("internlm2-1.8b").reduced().replace(dtype="float32")
+    mesh = AbstractMesh((1, model), ("data", "model"))
+    fn, specs, _ = lm.build_train_loss(cfg, mesh,
+                                       TrainHParams(schedule=schedule),
+                                       global_batch=4, seq_len=64)
+    params = jax.eval_shape(lambda: prm.init_params(specs,
+                                                    jax.random.PRNGKey(0)))
+    batch = {k: jax.ShapeDtypeStruct((4, 64), jnp.int32)
+             for k in ("tokens", "labels")}
+    with jax.sharding.use_abstract_mesh(mesh):
+        jx = jax.make_jaxpr(lambda p, b: fn(p, b)[0])(params, batch)
+    return str(jx).count("optimization_barrier")
+
+
+@pytest.mark.parametrize("model,schedule,barriers", [
+    # a layer body of attention + MLP over two sub-batches issues four
+    # reductions; each after the first waits for the one before it
+    (2, "oases", 3),
+    (2, "merak", 3),
+    # no TMP axes: no reduction to order
+    (1, "oases", 0),
+    # one batch: nothing to keep apart
+    (2, "megatron", 0),
+], ids=["oases-tp2", "merak-tp2", "oases-tp1", "megatron-tp2"])
+def test_sub_batch_reductions_are_ordered(model, schedule, barriers):
+    assert _forward_barriers(model, schedule) == barriers
+
+
+def test_reduction_order_mirrors_backward():
+    """The barrier transposes to a barrier on the cotangents, so the
+    backward reductions are ordered too, with no custom VJP."""
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+
+    from repro.core.axes import MeshInfo
+    from repro.core.schedule import TmpCtx, apply_layer, split_tree
+
+    mesh = AbstractMesh((1, 2), ("data", "model"))
+    ctx = TmpCtx(MeshInfo(mesh, ("data",), ("model",)), schedule="oases")
+
+    def part(p, x, a):
+        return ctx.row_matmul(jnp.tanh(x @ p["w1"]), p["w2"]), 0.0
+
+    def layer(p, x):
+        xs, _ = apply_layer([part, part], p, split_tree(x, 2), [{}, {}],
+                            "oases")
+        return jnp.concatenate(xs)
+
+    sharded = jax.shard_map(
+        layer, mesh=mesh,
+        in_specs=({"w1": P(None, "model"), "w2": P("model", None)}, P()),
+        out_specs=P(), check_vma=False)
+    p = {"w1": jax.ShapeDtypeStruct((8, 16), jnp.float32),
+         "w2": jax.ShapeDtypeStruct((16, 8), jnp.float32)}
+    x = jax.ShapeDtypeStruct((4, 8), jnp.float32)
+    fwd = str(jax.make_jaxpr(sharded)(p, x))
+    grad = str(jax.make_jaxpr(
+        jax.grad(lambda p, x: sharded(p, x).sum()))(p, x))
+    assert fwd.count("optimization_barrier") == 3
+    assert grad.count("optimization_barrier") == 2 * 3
+
+
+def test_reduction_order_changes_no_number():
+    from repro.core.schedule import _ReduceOrder
+
+    def f(xs, ordered):
+        order = _ReduceOrder()
+        outs = [order.reduce(lambda t: 2.0 * t, x) if ordered else 2.0 * x
+                for x in xs]
+        return sum(jnp.sum(jnp.sin(o) * (i + 1)) for i, o in enumerate(outs))
+
+    xs = [jax.random.normal(jax.random.PRNGKey(i), (4, 8)) for i in range(3)]
+    for fn in (f, jax.grad(f)):
+        ours = jax.jit(fn, static_argnums=1)(xs, True)
+        ref = jax.jit(fn, static_argnums=1)(xs, False)
+        for a, b in zip(jax.tree_util.tree_leaves(ours),
+                        jax.tree_util.tree_leaves(ref)):
+            np.testing.assert_array_equal(a, b)
