@@ -2,6 +2,7 @@
 cache, compile counts, per-layer metric readers, and the result line."""
 from __future__ import annotations
 
+import dataclasses
 import importlib
 import importlib.util
 import json
@@ -9,6 +10,7 @@ import math
 import os
 import sys
 import time
+import typing
 from pathlib import Path
 from typing import Dict, List, Optional
 
@@ -142,23 +144,75 @@ class CompileLog:
         return self.compiles - self._mark
 
 
+# keys of a configuration's ``model`` that are the benchmark's own and name
+# no field of the program's configuration
+BENCH_MODEL_KEYS = ("padded_vocab",)
+
+
 def program_config(cfg: dict):
     """The program's ``ArchConfig`` for a configuration file: the registry
-    entry it names, with every size the file states."""
+    entry it names, with every key of the file's ``model`` applied to the
+    field of that name.  A field that holds a dataclass (``moe``) takes an
+    object of that dataclass's fields, applied to the entry's own where it
+    has one; a list becomes a tuple.  The output head is untied unless the
+    file says otherwise.  A key that names no field raises."""
     sys.path.insert(0, str(CHECKOUT / "src"))
     from repro.configs.registry import get_config
-    m = cfg["model"]
-    return get_config(cfg["registry"]).replace(
-        num_layers=m["num_layers"], d_model=m["d_model"],
-        num_heads=m["num_heads"], num_kv_heads=m["num_kv_heads"],
-        head_dim=m["head_dim"], d_ff=m["d_ff"], vocab_size=m["vocab_size"],
-        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"],
-        dtype=m["dtype"], tie_embeddings=False)
+    base = get_config(cfg["registry"])
+    model = {k: v for k, v in cfg["model"].items()
+             if k not in BENCH_MODEL_KEYS}
+    return dataclasses.replace(
+        base, **{"tie_embeddings": False,
+                 **_fields(type(base), model, f"{cfg['name']}: model",
+                           base)})
+
+
+def _fields(kind, values: dict, where: str, base=None) -> dict:
+    """``values`` as fields of the dataclass ``kind``; an object given for
+    a field that holds a dataclass is applied to ``base``'s value of that
+    field where it has one."""
+    hints = typing.get_type_hints(kind)
+    unknown = sorted(set(values) - {f.name for f in dataclasses.fields(kind)})
+    if unknown:
+        raise KeyError(f"{where}: {unknown} name no field of "
+                       f"{kind.__name__}")
+    out = {}
+    for k, v in values.items():
+        if isinstance(v, dict):
+            sub = _dataclass_of(hints[k])
+            if sub is None:
+                raise TypeError(f"{where}.{k}: an object for a field that "
+                                f"holds no dataclass")
+            inner = getattr(base, k, None)
+            kw = _fields(sub, v, f"{where}.{k}", inner)
+            v = sub(**kw) if inner is None else dataclasses.replace(inner,
+                                                                    **kw)
+        elif isinstance(v, list):
+            v = tuple(v)
+        out[k] = v
+    return out
+
+
+def _dataclass_of(hint):
+    """The dataclass a field's type names, alone or in an ``Optional``."""
+    for t in (hint, *typing.get_args(hint)):
+        if dataclasses.is_dataclass(t):
+            return t
+    return None
 
 
 def reference(cfg: dict):
     path = BENCH / "references" / f"{cfg['reference']}.py"
     return _load(path, f"bench_reference_{cfg['reference']}")
+
+
+def count(cfg: dict, name: str):
+    """The configuration's own count ``name``: ``model_flops_per_token(
+    model, seq_len)`` or ``serve_tick(model, positions)`` of its reference
+    module where that defines it, else ``flops.py``'s for a dense
+    decoder."""
+    import flops as F
+    return getattr(reference(cfg), name, None) or getattr(F, name)
 
 
 def start_trace(trace_dir) -> dict:

@@ -1,5 +1,6 @@
 """Milliseconds per step in which a collective (all-reduce, all-gather,
-reduce-scatter, collective-permute) runs on chip 0, from the trace."""
+reduce-scatter, collective-permute, the two ends of an asynchronous
+all-reduce) runs on chip 0, from the trace."""
 
 
 def read(ctx):

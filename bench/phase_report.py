@@ -3,17 +3,16 @@
     python3 bench/phase_report.py --workload <cell> --seed <n> --seconds <s>
 
 Runs ``run.py``'s ``--trace 1`` path unchanged (its result line is printed
-as usual) and reads the same profile a second way, with ``phases.py``:
-chip 0's time per pass (``fwd``, ``bwd``, ``opt``, ``other``), collective
-and exposed time per pass and ``tmp.*`` scope, idle time per program span,
-and, for a serving cell, the requests' queue wait and prompt path and the
-slots' occupancy from the engine's own stamps and counters; and the
-collector's long pauses, to tell a host stall from device work.  It watches the
-run from outside: it keeps the profile before the cell deletes it, the
-compiled step's text, and the serving engine with the requests submitted to
-it.  The tables go to stderr; the last line of stdout is one JSON object
-with each number of ``phases.READERS``, the sums that check them, and the
-window's end-to-end numbers, taken with the profiler on for part of it.
+as usual) and prints what its readers were given (``ctx["phases"]``, the
+split of the same trace by ``phases.py``) as tables: chip 0's time per pass
+(``fwd``, ``bwd``, ``opt``, ``other``) and per named scope, collective and
+exposed time per pass and ``tmp.*`` scope, idle time per program span, and,
+for a serving cell, the requests' queue wait and prompt path and the slots'
+occupancy from the engine's own stamps and counters; and the collector's
+long pauses, to tell a host stall from device work.  The tables go to
+stderr; the last line of stdout is one JSON object with each number of
+``phases.READERS``, the sums that check them, and the window's end-to-end
+numbers, taken with the profiler on for part of it.
 """
 from __future__ import annotations
 
@@ -30,74 +29,6 @@ sys.path.insert(0, str(BENCH.parent / "src"))
 
 import harness as H  # noqa: E402  (its import marks the process start)
 import phases as P  # noqa: E402
-
-
-class Watch:
-    """What the run leaves behind for the phase split."""
-
-    def __init__(self):
-        self.trace = None           # (Trace, program spans)
-        self.hlo = None
-        self.engine = None
-        self.requests = []
-        self.marks = None           # engine counters at the first submit
-
-    def install(self, kind: str):
-        orig_from_dir = P.TR.Trace.from_dir.__func__
-
-        def from_dir(cls, trace_dir):
-            self.trace = P.load(trace_dir)
-            return orig_from_dir(cls, trace_dir)
-        P.TR.Trace.from_dir = classmethod(from_dir)
-        if kind == "train":
-            import train_cell as C
-            orig = C._traced
-
-            def _traced(cell, traced, hlo, *a, **k):
-                self.hlo = hlo
-                return orig(cell, traced, hlo, *a, **k)
-            C._traced = _traced
-        else:
-            import serve_cell as C
-            orig_build = C.build
-
-            def build(cfg, devs):
-                eng = orig_build(cfg, devs)
-                submit = eng.submit
-
-                def watched(req):
-                    if self.marks is None:
-                        self.marks = dict(eng.stats)
-                    self.requests.append(req)
-                    submit(req)
-                eng.submit = watched
-                self.engine = eng
-                return eng
-            C.build = build
-
-    def context(self, kind: str) -> dict:
-        tr, spans = self.trace
-        lo, hi = tr.window()
-        if kind == "train":
-            module = P.F.HloDots(self.hlo).module
-            red = P.TR.reduce(tr, module=module)
-            ph = P.reduce(tr, spans, P.attribute(self.hlo), module=module)
-            return {"kind": kind, "trace": red, "phases": ph,
-                    "steps": red["module_runs"]}
-        red = P.TR.reduce(tr)
-        ph = P.reduce(tr, spans)
-        stats, m = self.engine.stats, self.marks or {}
-        ticks = [(n, s, e) for n, s, e in tr.spans
-                 if n == "bench.tick" and lo <= s and e <= hi]
-        return {"kind": kind, "trace": red, "phases": ph,
-                "ticks_traced": sum(n == "serve.dispatch" and lo <= s <= hi
-                                    for n, s, _ in spans),
-                "traced_tick_s": [e - s for _, s, e in ticks],
-                "request_times": [(r.t_submit, r.t_admit, r.t_first)
-                                  for r in self.requests if r.done],
-                "slot_ticks": stats["slot_ticks"] - m.get("slot_ticks", 0),
-                "ticks": stats["steps"] - m.get("steps", 0),
-                "slots": self.engine.slots}
 
 
 class Collections:
@@ -128,14 +59,12 @@ def main(argv=None, *, cell: H.Cell = None, devices=None):
     args = ap.parse_args(argv)
     cell = cell if cell is not None else H.Cell(args.workload)
     kind = cell.config["kind"]
-    watch = Watch()
-    watch.install(kind)
     collections = Collections()
     import run as R
     r = R.main(["--workload", cell.name, "--seed", str(args.seed),
                 "--seconds", str(args.seconds), "--trace", "1"],
                cell=cell, devices=devices)
-    ctx = watch.context(kind)
+    ctx = r["ctx"]
     ph, red = ctx["phases"], ctx["trace"]
     out = {"workload": cell.name, "seed": args.seed,
            "correct": r["correct"], "phases": P.read_all(ctx),
@@ -152,6 +81,8 @@ def main(argv=None, *, cell: H.Cell = None, devices=None):
             steps=steps,
             busy_ms_by_pass={k: 1e3 * v / steps
                              for k, v in ph["busy_s_by_pass"].items()},
+            busy_ms_by_scope={k: 1e3 * v / steps
+                              for k, v in ph["busy_s_by_scope"].items()},
             step_device_ms=1e3 * red["busy_s_dev0"] / steps,
             exposed_ms_by_pass={k: 1e3 * v / steps
                                 for k, v in ph["exposed_s_by_pass"].items()},

@@ -18,23 +18,29 @@ This module splits it by what the program says about itself:
 * **TMP scope**: the ``tmp.*`` components of the path (``core/tmp.py`` and
   ``core/schedule.py`` name the schedule phases), such as
   ``tmp.oases.sub0/tmp.reduce``;
+* **named scopes**: every component of the path that the program named
+  itself (``jax.named_scope``, ``obs.phase_scope``): a dotted name whose
+  parts start with a letter, such as ``tmp.oases.sub0``, ``tmp.reduce``,
+  ``optim.adamw`` or ``moe.dispatch``; not one of JAX's own
+  (``jit(step)``, ``transpose(jvp())``, ``while``, ``dot_general``) nor an
+  instruction's name the compiler wrote there (``reshape.799``);
 * **program spans**: the host events the program opens itself
   (``obs.trace_annotation``), named ``serve.*`` or ``train.*``, on the same
-  clock as the device ops.
+  clock as the device ops (``trace.Trace.program_spans``).
 
 Chip 0's busy time is split among the passes by the innermost op running at
-each instant, so the passes add up to ``busy_s_dev0``; its collective and
-exposed time (as ``trace.reduce`` defines them) by the innermost collective,
-so they add up to ``collective_s`` and ``exposed_collective_s``; its idle
-gaps by the innermost program span over each gap's middle.  ``READERS``
-turns these into per-step and per-tick numbers.
+each instant, so the passes add up to ``busy_s_dev0``; among the named
+scopes the same way, each piece counted once under every scope of its op's
+path, so a scope's time holds that of the scopes inside it; its collective
+and exposed time (as ``trace.reduce`` defines them) by the innermost
+collective, so they add up to ``collective_s`` and ``exposed_collective_s``;
+its idle gaps by the innermost program span over each gap's middle.
+``READERS`` turns these into per-step and per-tick numbers.
 """
 from __future__ import annotations
 
 import bisect
-import glob
 import math
-import os
 import re
 from collections import defaultdict
 from typing import Dict, Iterable, List, Optional, Tuple
@@ -45,15 +51,17 @@ import harness as H
 TR = H._load(H.BENCH / "trace.py", "bench_trace")
 
 PASSES = ("fwd", "bwd", "opt", "other")
-PROGRAM_SPAN_PREFIXES = ("serve.", "train.")
 NO_SPAN = "outside program spans"
 NO_SCOPE = "(no tmp scope)"
 
 _OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _ROOT_RE = re.compile(r"^\s*ROOT\s+%?([\w\.\-]+)\s*=")
 _CALLED_RE = re.compile(r"(?:body|condition|to_apply|calls)=%?([\w\.\-]+)")
+_SCOPE_RE = re.compile(r"^[A-Za-z_]\w*(?:\.[A-Za-z_]\w*)+$")
 
 Labelled = Tuple[float, float, object]
+Where = Tuple[str, str, Tuple[str, ...]]   # (pass, TMP scope, named scopes)
+OTHER: Where = ("other", "", ())
 
 
 # --------------------------------------------------------------------------
@@ -73,9 +81,15 @@ def tmp_scope_of(path: str) -> str:
     return "/".join(c for c in path.split("/") if c.startswith("tmp."))
 
 
-def attribute(text: str) -> Dict[str, Tuple[str, str]]:
-    """{instruction name: (pass, TMP scope)} for every instruction of a
-    compiled module's text (``compiled.as_text()``)."""
+def scopes_of(path: str) -> Tuple[str, ...]:
+    """The named scopes of a path, outermost first, each once."""
+    return tuple(dict.fromkeys(c for c in path.split("/")
+                               if _SCOPE_RE.match(c)))
+
+
+def scope_paths(text: str) -> Dict[str, str]:
+    """{instruction name: scope path} for every instruction of a compiled
+    module's text (``compiled.as_text()``)."""
     comps, _, _, _ = F.parse_hlo(text)
     root_names = {m.group(1) for m in map(_ROOT_RE.match, text.splitlines())
                   if m}
@@ -106,43 +120,20 @@ def attribute(text: str) -> Dict[str, Tuple[str, str]]:
             p = own(comp, name)
         return p
 
-    out = {}
-    for comp, names in paths.items():
-        for name in names:
-            p = path(comp, name)
-            out[name] = (pass_of(p), tmp_scope_of(p))
-    return out
+    return {name: path(comp, name)
+            for comp, names in paths.items() for name in names}
+
+
+def attribute(text: str) -> Dict[str, Tuple[str, str]]:
+    """{instruction name: (pass, TMP scope)} for every instruction of a
+    compiled module's text."""
+    return {name: (pass_of(p), tmp_scope_of(p))
+            for name, p in scope_paths(text).items()}
 
 
 # --------------------------------------------------------------------------
 # the trace
 # --------------------------------------------------------------------------
-def program_spans(pd) -> List[Tuple[str, float, float]]:
-    """Host events the program opened itself, in seconds."""
-    out = []
-    for plane in pd.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith(PROGRAM_SPAN_PREFIXES):
-                    s = e.start_ns * 1e-9
-                    out.append((e.name, s, s + e.duration_ns * 1e-9))
-    return out
-
-
-def load(trace_dir: str):
-    """(``trace.Trace``, program spans) of the newest profile under
-    ``trace_dir``, read once."""
-    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if not files:
-        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_file(max(files, key=os.path.getmtime))
-    return TR.Trace.from_profile(pd), program_spans(pd)
-
-
 def innermost(items: Iterable[Labelled]) -> List[Labelled]:
     """Split the union of labelled intervals into pieces, each carrying
     the label of the interval that started last among those covering it
@@ -181,34 +172,36 @@ def _sum(pieces: Iterable[Labelled]) -> Dict[object, float]:
     return out
 
 
-def reduce(tr, spans: List[Tuple[str, float, float]],
-           passes: Optional[Dict[str, Tuple[str, str]]] = None, *,
+def reduce(tr, paths: Optional[Dict[str, str]] = None, *,
            module: Optional[str] = None, top: int = 10) -> dict:
-    """Chip 0's time in the traced window by pass, TMP scope and program
-    span, and its ``top`` longest idle gaps with their program span.
-    ``passes`` is ``attribute()`` of the module named ``module``; ops of
-    other programs, and any op without it, count as ``other``."""
+    """Chip 0's time in the traced window by pass, named scope, TMP scope
+    and program span, and its ``top`` longest idle gaps with their program
+    span.  ``paths`` is ``scope_paths()`` of the module named ``module``;
+    ops of other programs, and any op without a path, count as ``other``
+    with no scope."""
     lo, hi = tr.window()
     d0 = sorted(tr.ops)[0]
-    passes = passes or {}
+    where = {p: (pass_of(p), tmp_scope_of(p), scopes_of(p))
+             for p in set((paths or {}).values())}
+    passes = {name: where[p] for name, p in (paths or {}).items()}
     runs = sorted((s, e) for n, s, e in tr.modules.get(d0, [])
                   if e > lo and s < hi
                   and (module is None or n.split("(")[0] == module))
 
-    def label(o) -> Tuple[str, str]:
+    def label(o) -> Where:
         if module is not None and not any(s <= o.start < e for s, e in runs):
-            return ("other", "")
-        return passes.get(o.name, ("other", ""))
+            return OTHER
+        return passes.get(o.name, OTHER)
 
     ops = [(max(o.start, lo), min(o.end, hi), o) for o in tr.ops[d0]
            if o.end > lo and o.start < hi]
-    busy = innermost((s, e, label(o)[0]) for s, e, o in ops)
+    busy = innermost((s, e, label(o)) for s, e, o in ops)
 
     # collective and exposed time as ``trace.reduce`` counts them: control
     # ops (loops, calls) are neither
     work = [(s, e, o) for s, e, o in ops if o.kind not in TR._CONTROL]
-    colls = [(s, e, label(o)) for s, e, o in work if o.collective()]
-    colls += [(s, e, label(o)) for s, e, o in (
+    colls = [(s, e, label(o)[:2]) for s, e, o in work if o.collective()]
+    colls += [(s, e, label(o)[:2]) for s, e, o in (
         (max(o.start, lo), min(o.end, hi), o)
         for o in tr.async_ops.get(d0, []) if o.collective())
         if e > s]
@@ -225,15 +218,23 @@ def reduce(tr, spans: List[Tuple[str, float, float]],
     labelled = []
     for s, e in gaps:
         mid = 0.5 * (s + e)
-        cover = [(e2 - s2, n) for n, s2, e2 in spans if s2 <= mid <= e2]
+        cover = [(e2 - s2, n) for n, s2, e2 in tr.program_spans
+                 if s2 <= mid <= e2]
         labelled.append([min(cover)[1] if cover else NO_SPAN, e - s])
         idle[labelled[-1][0]] += e - s
 
-    by_pass = _sum(busy)
+    by_pass: Dict[str, float] = defaultdict(float)
+    by_scope: Dict[str, float] = defaultdict(float)
+    for (p, _, named), t in _sum(busy).items():
+        by_pass[p] += t
+        for scope in named:
+            by_scope[scope] += t
     cs, xs = _sum(coll), _sum(exposed)
     scopes = sorted(set(cs), key=lambda k: (-cs[k], k))
     return {
         "busy_s_by_pass": {p: by_pass.get(p, 0.0) for p in PASSES},
+        "busy_s_by_scope": dict(sorted(by_scope.items(),
+                                       key=lambda kv: (-kv[1], kv[0]))),
         "collective_s_by_pass": {p: sum(v for k, v in cs.items()
                                         if k[0] == p) for p in PASSES},
         "exposed_s_by_pass": {p: sum(v for k, v in xs.items()
@@ -355,10 +356,13 @@ def read_all(ctx) -> Dict[str, float]:
 
 
 def tables(ph: dict, per: int, unit: str) -> str:
-    """The per-TMP-scope and idle-by-span tables, in ms per ``unit``."""
+    """The per-scope and idle-by-span tables, in ms per ``unit``."""
     n = max(per, 1)
-    rows = [f"collective ms per {unit} by pass and TMP scope "
-            f"(collective, exposed):"]
+    rows = [f"chip-0 busy ms per {unit} by named scope:"]
+    for scope, t in ph["busy_s_by_scope"].items():
+        rows.append(f"  {scope:40s} {1e3 * t / n:10.4f}")
+    rows.append(f"collective ms per {unit} by pass and TMP scope "
+                f"(collective, exposed):")
     for p, scope, c, x in ph["collective_by_scope"]:
         rows.append(f"  {p:5s} {scope:40s} {1e3 * c / n:10.4f} "
                     f"{1e3 * x / n:10.4f}")

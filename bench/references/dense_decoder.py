@@ -12,6 +12,10 @@ Written from the configuration alone, in ``jax.numpy``: it imports nothing
 of the program and takes nothing it made; weights come from the seed
 through ``bench/weights.py``, in the layout the program's tree names.
 
+The configuration's operation and byte counts, which the harness takes from
+its reference module (``harness.count``), are ``flops.py``'s for a dense
+decoder: ``model_flops_per_token`` and ``serve_tick``.
+
 ``prec`` is ``"f32"`` (float32, every matmul at ``HIGHEST``) or ``"fp8"``,
 the control: each matmul's operands rounded to an 8-bit float (e4m3) with a
 scale per tensor (gradients pass the rounding straight through).
@@ -29,10 +33,14 @@ import numpy as np
 from jax import lax
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+import flops as F  # noqa: E402
 import weights as W  # noqa: E402
 
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
+
+model_flops_per_token = F.model_flops_per_token
+serve_tick = F.serve_tick
 
 
 # --------------------------------------------------------------------------

@@ -39,7 +39,6 @@ def plan_for(acfg, hp, mesh):
 
 
 def compile_train(cfg, topo):
-    import jax
     from repro.configs.base import TrainHParams
     from repro.launch import steps
     o = cfg["optimizer"]
@@ -57,7 +56,8 @@ def compile_train(cfg, topo):
     args = steps.train_abstract_inputs(acfg, mesh, hp,
                                        global_batch=cfg["global_batch"],
                                        seq_len=cfg["seq_len"], plan=plan)
-    return jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
+    return steps.jit_train_step(fn, mesh, donate_argnums=(0, 1)).lower(
+        *args).compile()
 
 
 def compile_serve(cfg, topo):
@@ -101,7 +101,8 @@ def main(names):
               f"collectives {colls}", flush=True)
         if cfg["kind"] == "train":
             dots = F.executed_dot_flops(text)
-            model = F.model_flops_per_token(cfg["model"], cfg["seq_len"]) \
+            model = H.count(cfg, "model_flops_per_token")(
+                cfg["model"], cfg["seq_len"]) \
                 * cfg["global_batch"] * cfg["seq_len"] / cell.chips
             print(f"  executed matmul {dots:.6g} FLOP per step per chip, "
                   f"model {model:.6g} ({dots / model:.4f}x)", flush=True)

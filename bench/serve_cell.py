@@ -20,6 +20,7 @@ import numpy as np
 import faults as FL
 import flops as F
 import harness as H
+import phases as PH
 import traffic as T
 import weights as W
 
@@ -133,7 +134,8 @@ def run(cell: H.Cell, *, seed: int, seconds: float, trace: bool, devs,
         eng.step()
         if clog.compiles == before:
             break
-    steps0, prompt0 = eng.stats["steps"], eng.stats["prompt_tokens"]
+    steps0, prompt0, slot0 = (eng.stats[k] for k in (
+        "steps", "prompt_tokens", "slot_ticks"))
     setup_s = time.perf_counter() - H.T0
     setup_compile_s = clog.seconds
 
@@ -144,7 +146,7 @@ def run(cell: H.Cell, *, seed: int, seconds: float, trace: bool, devs,
     due: Dict[int, float] = {}
     times: Dict[int, List[float]] = {}
     live: List[Request] = []
-    late, ticks, tick_pos = [], [], []
+    late, ticks, tick_pos, traced_ticks = [], [], [], []
     traced = None
     backlog = {}
     i = 0
@@ -175,7 +177,8 @@ def run(cell: H.Cell, *, seed: int, seconds: float, trace: bool, devs,
                 and now >= traced["t"] + 3.0:
             H.stop_trace(traced)
         if live:
-            if traced is not None and not traced["done"]:
+            in_trace = traced is not None and not traced["done"]
+            if in_trace:
                 tick_pos.append([int(eng.pos[s]) for s in range(eng.slots)
                                  if eng.active[s] is not None])
             ts = time.perf_counter()
@@ -183,6 +186,8 @@ def run(cell: H.Cell, *, seed: int, seconds: float, trace: bool, devs,
                 eng.step()
             te = time.perf_counter()
             ticks.append(te - ts)
+            if in_trace:
+                traced_ticks.append(te - ts)
             still = []
             if any(r._slot is None for r in live):
                 for slot, q in enumerate(eng.active):
@@ -215,12 +220,18 @@ def run(cell: H.Cell, *, seed: int, seconds: float, trace: bool, devs,
     unfinished = sum(not r.done for r in reqs.values())
     ticks_n = eng.stats["steps"] - steps0
     prompt_tok = eng.stats["prompt_tokens"] - prompt0
+    slot_ticks = eng.stats["slot_ticks"] - slot0
     H.log(f"window: {len(reqs)} requests due, {unfinished} unfinished, "
           f"{ticks_n} ticks, {sum(len(t) for t in times.values())} tokens, "
           f"ran {t_end - t0} s (close at {seconds} s), generator late "
           f"median {np.median(late) if late else 0} s max "
           f"{max(late) if late else 0} s, {window_compiles} compiles")
     peak = H.memory_peak(devs)
+    # the decode step's compiled text, for the phase split (from the cache)
+    hlo = eng.decode_fn.lower(
+        eng.params, eng.state, jax.numpy.asarray(eng.cur_tok),
+        jax.numpy.asarray(eng.pos)).compile().as_text() if trace else None
+    slots = eng.slots
     eng.params = eng.state = None
     del eng
     gc.collect()
@@ -256,26 +267,39 @@ def run(cell: H.Cell, *, seed: int, seconds: float, trace: bool, devs,
               "failed": unfinished, "checks": checks,
               "memory_peak_bytes": peak, "e2e": e2e}
     if trace:
-        peaks = H.peaks(devs[0].device_kind)
-        TR = H._load(H.BENCH / "trace.py", "bench_trace")
-        red = TR.reduce(TR.Trace.from_dir(str(trace_dir)))
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        bound = 0.0
-        for pos in tick_pos:
-            fl, nb = F.serve_tick(model, pos)
-            bound += max(fl / peaks["bf16_flops_per_s"],
-                         nb / peaks["hbm_bytes_per_s"])
-        ctx = {"kind": "serve", "trace": red, "setup_compile_s": setup_compile_s,
-               "tick_s": ticks, "ticks": ticks_n, "prompt_tokens": prompt_tok,
-               "ticks_traced": len(tick_pos), "roofline_s": bound}
-        H.log(f"trace: {len(tick_pos)} ticks, busy {red['busy_s']} s of "
-              f"{red['window_s']} s, roofline bound {bound} s")
-        result.update({"per_layer": H.read_per_layer(cell, ctx),
-                       "busy_s": red["busy_s"], "window_s": red["window_s"],
-                       "breakdown": {"device_ops": red["device_ops"],
-                                     "idle_gaps": red["idle_gaps"]}})
+        result.update(_traced(cell, trace_dir, hlo, {
+            "kind": "serve", "config": cfg,
+            "setup_compile_s": setup_compile_s, "tick_s": ticks,
+            "ticks": ticks_n, "prompt_tokens": prompt_tok,
+            "slot_ticks": slot_ticks, "slots": slots,
+            "ticks_traced": len(tick_pos), "traced_tick_s": traced_ticks,
+            "request_times": [(r.t_submit, r.t_admit, r.t_first)
+                              for r in finished]},
+            tick_pos, H.peaks(devs[0].device_kind)))
     return result
 
+
+def _traced(cell, trace_dir, hlo, ctx, tick_pos, peaks) -> dict:
+    """The per-layer metrics of the traced ticks, with everything their
+    readers may read, taken from the trace before it is deleted."""
+    module = F.HloDots(hlo).module
+    tr = PH.TR.Trace.from_dir(str(trace_dir))
+    red = PH.TR.reduce(tr)
+    ph = PH.reduce(tr, PH.scope_paths(hlo), module=module)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    tick_counts = H.count(cell.config, "serve_tick")
+    bound = 0.0
+    for pos in tick_pos:
+        fl, nb = tick_counts(cell.config["model"], pos)
+        bound += max(fl / peaks["bf16_flops_per_s"],
+                     nb / peaks["hbm_bytes_per_s"])
+    ctx.update(trace=red, phases=ph, roofline_s=bound)
+    H.log(f"trace: {len(tick_pos)} ticks, busy {red['busy_s']} s of "
+          f"{red['window_s']} s, roofline bound {bound} s")
+    return {"per_layer": H.read_per_layer(cell, ctx), "ctx": ctx,
+            "busy_s": red["busy_s"], "window_s": red["window_s"],
+            "breakdown": {"device_ops": red["device_ops"],
+                          "idle_gaps": red["idle_gaps"]}}
 
 
 def readings(cell: H.Cell, args, faults, *, devs, clog, emit):

@@ -6,7 +6,8 @@ executed HLO instruction (its text is the instruction: ``%name = shape
 kind(...), ..., calls=%computation``), ``Async XLA Ops`` the spans of
 asynchronous ones, and ``XLA Modules`` one event per program run.  Host
 threads are planes ``/host:...``; the benchmark's own spans are events named
-``bench.<what>``, on the same clock.
+``bench.<what>``, the program's own ``serve.<what>`` or ``train.<what>``, on
+the same clock.
 
 The traced window is the benchmark's span ``bench.window``.  Within it:
 
@@ -14,7 +15,12 @@ The traced window is the benchmark's span ``bench.window``.  Within it:
 * per-op time, summed by instruction name;
 * collective time: the union of the intervals in which a collective runs
   (its ops, and the spans of asynchronous ones); exposed: the part of it in
-  which no other op runs on that chip;
+  which no other op runs on that chip.  The TPU compiler's asynchronous
+  all-reduce is an ``async-collective-start`` and an
+  ``async-collective-done`` (both ``fusion`` instructions) with the
+  reduction's steps fused into the compute between them: the two ends are
+  collectives, the fusion that carries the steps (it calls an
+  ``async_collective_fusion.*`` computation) is compute;
 * matmul time: the ops that call a computation holding a matmul (the set
   comes from the compiled module, see ``flops.HloDots``);
 * idle gaps, each labelled with the innermost ``bench.*`` span that covers
@@ -31,7 +37,9 @@ from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 COLLECTIVE_WORDS = ("all-reduce", "all-gather", "reduce-scatter",
                     "collective-permute", "all-to-all", "ragged-all-to-all",
-                    "collective-broadcast", "send", "recv")
+                    "collective-broadcast", "send", "recv",
+                    "async-collective-start", "async-collective-done")
+PROGRAM_SPAN_PREFIXES = ("serve.", "train.")
 _KIND_RE = re.compile(r" ([a-z][\w\-]*)\(")
 _CALLS_RE = re.compile(r"calls=%?([\w\.\-]+)")
 _CONTROL = ("while", "call", "conditional")
@@ -92,14 +100,16 @@ def clip(intervals: Iterable[Interval], lo: float, hi: float):
 
 
 class Trace:
-    """Device ops and host spans of one trace, in seconds."""
+    """Device ops and host spans of one trace, in seconds: the
+    benchmark's spans in ``spans``, the program's in ``program_spans``."""
 
     def __init__(self, ops: Dict[int, List[Op]],
                  async_ops: Dict[int, List[Op]],
                  modules: Dict[int, List[Tuple[str, float, float]]],
-                 spans: List[Tuple[str, float, float]]):
+                 spans: List[Tuple[str, float, float]],
+                 program_spans: List[Tuple[str, float, float]] = ()):
         self.ops, self.async_ops, self.modules = ops, async_ops, modules
-        self.spans = spans
+        self.spans, self.program_spans = spans, list(program_spans)
 
     @classmethod
     def from_dir(cls, trace_dir: str) -> "Trace":
@@ -115,7 +125,7 @@ class Trace:
     def from_profile(cls, pd) -> "Trace":
         ops, async_ops, modules = (defaultdict(list), defaultdict(list),
                                    defaultdict(list))
-        spans = []
+        spans, program = [], []
         for plane in pd.planes:
             m = re.match(r"/device:[A-Z]+:(\d+)$", plane.name)
             if m:
@@ -134,10 +144,15 @@ class Trace:
                 for line in plane.lines:
                     for e in line.events:
                         if e.name.startswith("bench."):
-                            s = e.start_ns * 1e-9
-                            spans.append((e.name, s,
-                                          s + e.duration_ns * 1e-9))
-        return cls(dict(ops), dict(async_ops), dict(modules), spans)
+                            into = spans
+                        elif e.name.startswith(PROGRAM_SPAN_PREFIXES):
+                            into = program
+                        else:
+                            continue
+                        s = e.start_ns * 1e-9
+                        into.append((e.name, s, s + e.duration_ns * 1e-9))
+        return cls(dict(ops), dict(async_ops), dict(modules), spans,
+                   program)
 
     def window(self) -> Interval:
         w = [(s, e) for n, s, e in self.spans if n == "bench.window"]

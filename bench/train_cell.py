@@ -19,6 +19,7 @@ import numpy as np
 import faults as FL
 import flops as F
 import harness as H
+import phases as PH
 import traffic as T
 import weights as W
 
@@ -247,7 +248,7 @@ def run(cell: H.Cell, *, seed: int, seconds: float, trace: bool, devs,
 
     peaks = H.peaks(devs[0].device_kind)
     tokens_per_s = steps * B * S / (t_end - t_start)
-    mf_tok = F.model_flops_per_token(model, S)
+    mf_tok = H.count(cfg, "model_flops_per_token")(model, S)
     e2e = {"setup_s": setup_s, "train_tokens_per_s": tokens_per_s,
            "train_mfu": 100.0 * mf_tok * tokens_per_s
            / (len(devs) * peaks["bf16_flops_per_s"])}
@@ -263,13 +264,16 @@ def run(cell: H.Cell, *, seed: int, seconds: float, trace: bool, devs,
 
 def _traced(cell, traced, hlo, waits, setup_compile_s, peaks,
             model_flops_step, chips) -> dict:
-    TR = H._load(H.BENCH / "trace.py", "bench_trace")
+    """The per-layer metrics of the traced steps, with everything their
+    readers may read, taken from the trace before it is deleted."""
     dots = F.HloDots(hlo)
-    red = TR.reduce(TR.Trace.from_dir(str(traced["dir"])),
-                    module=dots.module,
-                    matmul_computations=dots.matmul_computations())
+    tr = PH.TR.Trace.from_dir(str(traced["dir"]))
+    red = PH.TR.reduce(tr, module=dots.module,
+                      matmul_computations=dots.matmul_computations())
+    ph = PH.reduce(tr, PH.scope_paths(hlo), module=dots.module)
     shutil.rmtree(traced["dir"], ignore_errors=True)
-    ctx = {"kind": "train", "trace": red, "steps": red["module_runs"],
+    ctx = {"kind": "train", "config": cell.config, "trace": red,
+           "phases": ph, "steps": red["module_runs"],
            "setup_compile_s": setup_compile_s, "input_wait_s": waits,
            "executed_dot_flops_per_step": dots.executed_flops(),
            "model_flops_per_step": model_flops_step, "chips": chips,
@@ -277,7 +281,7 @@ def _traced(cell, traced, hlo, waits, setup_compile_s, peaks,
     H.log(f"trace: {red['module_runs']} step runs, busy {red['busy_s']} s "
           f"of {red['window_s']} s, matmul {red['matmul_s']} s, "
           f"collective {red['collective_s']} s")
-    return {"per_layer": H.read_per_layer(cell, ctx),
+    return {"per_layer": H.read_per_layer(cell, ctx), "ctx": ctx,
             "busy_s": red["busy_s"], "window_s": red["window_s"],
             "breakdown": {"device_ops": red["device_ops"],
                           "idle_gaps": red["idle_gaps"]}}

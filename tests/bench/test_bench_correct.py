@@ -117,11 +117,9 @@ def test_four_chip_training(fault, tmp_path):
     assert r["correct"] == (fault is None), r["checks"]
 
 
-@pytest.mark.parametrize("name", [TRAIN, SERVE])
-def test_traced_run_reads_its_per_layer_metrics(name, monkeypatch):
+def _synthetic_trace(monkeypatch, name):
     """A CPU has no device plane to trace: the run's own profile is
-    swapped for a synthetic one, and every per-layer metric of the cell
-    that has something to read comes out, with the breakdown."""
+    swapped for a synthetic one."""
     import test_bench_harness as TH
     TR = H._load(H.BENCH / "trace.py", "bench_trace")
     real = TR.Trace.from_dir
@@ -134,6 +132,13 @@ def test_traced_run_reads_its_per_layer_metrics(name, monkeypatch):
         return tr
 
     monkeypatch.setattr(TR.Trace, "from_dir", staticmethod(synthetic))
+
+
+@pytest.mark.parametrize("name", [TRAIN, SERVE])
+def test_traced_run_reads_its_per_layer_metrics(name, monkeypatch):
+    """Every per-layer metric of the cell that has something to read in
+    the synthetic trace comes out, with the breakdown."""
+    _synthetic_trace(monkeypatch, name)
     cell = tiny.tiny_cell(name)
     r = tiny.run(cell, seconds=3.0, trace=1)
     want = {m["name"] for m in cell.per_layer()}
@@ -143,3 +148,24 @@ def test_traced_run_reads_its_per_layer_metrics(name, monkeypatch):
     assert want <= set(r["per_layer"]), (want, r["per_layer"])
     assert r["busy_s"] > 0 and r["window_s"] > 0
     assert len(r["breakdown"]["device_ops"]) <= 10
+    assert r["ctx"]["config"] == cell.config
+
+
+@pytest.mark.parametrize("name", [TRAIN, SERVE])
+def test_phase_report_reads_the_runs_phases(name, monkeypatch):
+    """``phase_report.py`` reads the phase split that the run handed its
+    readers: its numbers are the result line's."""
+    import jax
+    import phase_report as PR
+    _synthetic_trace(monkeypatch, name)
+    out = PR.main(["--workload", name, "--seed", "5", "--seconds", "3"],
+                  cell=tiny.tiny_cell(name), devices=jax.devices()[:1])
+    shared = set(out["phases"]) & set(out["per_layer"])
+    assert len(shared) >= 3
+    assert {k: out["phases"][k] for k in shared} == \
+        {k: out["per_layer"][k] for k in shared}
+    if name == TRAIN:
+        assert sum(out["busy_ms_by_pass"].values()) == \
+            pytest.approx(out["step_device_ms"])
+    else:
+        assert out["finished"] > 0 and out["stamps_ordered"]

@@ -126,6 +126,43 @@ def test_op_parse():
     assert TR.Op(AR, 0, 1).kind == "all-reduce"
 
 
+START = ("%async-collective-start = (bf16[8]{0}, bf16[8]{0}) "
+         "fusion(bf16[8]{0} %x), kind=kCustom, calls=%fused_computation.738")
+CARRY = ("%fusion.1056 = (bf16[8]{0}, bf16[8]{0}) fusion(bf16[8]{0} %a), "
+         "kind=kOutput, calls=%async_collective_fusion.1056")
+DONE = ("%async-collective-done = bf16[8]{0} fusion(bf16[8]{0} %b), "
+        "kind=kCustom, calls=%fused_computation.740")
+ARS = ("%all-reduce-start.3 = f32[8]{0} all-reduce-start(f32[8]{0} %x), "
+       "to_apply=%sum")
+ARD = ("%all-reduce-done.3 = f32[8]{0} all-reduce-done(f32[8]{0} "
+       "%all-reduce-start.3)")
+
+
+def test_async_all_reduce_ends_are_exposed_collectives():
+    """The TPU's asynchronous all-reduce: its start and done are
+    collectives, exposed where nothing runs beside them; the fusion that
+    carries the reduction's steps is a matmul kernel, not a collective."""
+    from jax.profiler import ProfileData
+    device = [(MM, 10, 30), (START, 30, 32), (CARRY, 32, 60),
+              (DONE, 60, 70), (ARS, 75, 76), (EW, 76, 80), (ARD, 80, 82)]
+    pd = ProfileData.from_text_proto(_xspace(
+        device, [], [("jit_train_step(1)", 10, 82)],
+        [("bench.window", 0, 100)]))
+    red = TR.reduce(TR.Trace.from_profile(pd), module="jit_train_step",
+                    matmul_computations={"fused_computation.7",
+                                         "async_collective_fusion.1056"})
+    us = 1e-6
+    assert red["collective_s"] == pytest.approx((2 + 10 + 1 + 2) * us)
+    assert red["exposed_collective_s"] == pytest.approx(15 * us)
+    assert red["matmul_s"] == pytest.approx((20 + 28) * us)
+    names = dict(red["device_ops"])
+    assert names["async-collective-done [collective]"] == \
+        pytest.approx(10 * us)
+    assert names["fusion.1056 [matmul]"] == pytest.approx(28 * us)
+    assert not TR.Op(CARRY, 0, 1).collective()
+    assert all(TR.Op(x, 0, 1).collective() for x in (START, DONE, ARS, ARD))
+
+
 # --------------------------------------------------------------------------
 # FLOP counts
 # --------------------------------------------------------------------------
@@ -135,6 +172,16 @@ def test_model_flops_per_token(config, gflop):
     cfg = H.load_json(BENCH / "configs" / f"{config}.json")
     f = F.model_flops_per_token(cfg["model"], cfg["seq_len"])
     assert abs(f / 1e9 - gflop) < 0.01
+
+
+@pytest.mark.parametrize("config", ["gpt-h1024", "gpt-h2048-tmp4",
+                                    "internlm2-1.8b"])
+def test_counts_come_from_the_reference(config):
+    """The dense decoder's reference states its counts as ``flops.py``'s,
+    so each cell's FLOPs and bytes are those of the dense formula."""
+    cfg = H.load_json(BENCH / "configs" / f"{config}.json")
+    assert H.count(cfg, "model_flops_per_token") is F.model_flops_per_token
+    assert H.count(cfg, "serve_tick") is F.serve_tick
 
 
 def _module(body: str) -> str:
@@ -331,6 +378,54 @@ def test_benchmark_files():
         assert m["moves"] in e2e
     for m in bm["end_to_end"]:
         assert m["source"] in ("host_clock", "device_trace")
+
+
+@pytest.mark.parametrize("config", ["gpt-h1024", "gpt-h2048-tmp4",
+                                    "internlm2-1.8b"])
+def test_program_config_as_before(config):
+    """Every key of the three files reaches the program as the ten named
+    sizes always did."""
+    from repro.configs.registry import get_config
+    cfg = H.load_json(BENCH / "configs" / f"{config}.json")
+    m = cfg["model"]
+    before = get_config(cfg["registry"]).replace(
+        num_layers=m["num_layers"], d_model=m["d_model"],
+        num_heads=m["num_heads"], num_kv_heads=m["num_kv_heads"],
+        head_dim=m["head_dim"], d_ff=m["d_ff"], vocab_size=m["vocab_size"],
+        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"],
+        dtype=m["dtype"], tie_embeddings=False)
+    assert H.program_config(cfg) == before
+
+
+def test_program_config_takes_every_field():
+    """A key outside the old ten reaches the program: an object for a
+    dataclass field is applied to the entry's own, a list is a tuple."""
+    from repro.configs.base import MoEConfig
+    from repro.configs.registry import get_config
+    cfg = {"name": "granite-moe-share", "registry": "granite-moe-3b-a800m",
+           "model": {"moe": {"num_experts": 8}, "window": 256,
+                     "layer_pattern": ["global", "local"],
+                     "padded_vocab": 49408, "tie_embeddings": True}}
+    a = H.program_config(cfg)
+    assert a.moe == MoEConfig(num_experts=8, top_k=8, sharding="tmp")
+    assert (a.window, a.layer_pattern, a.tie_embeddings) == \
+        (256, ("global", "local"), True)
+    cfg["model"] = {"moe": {"num_experts": 4, "top_k": 2}}
+    cfg["registry"] = "gpt-h1024"
+    assert get_config("gpt-h1024").moe is None
+    assert H.program_config(cfg).moe == MoEConfig(num_experts=4, top_k=2)
+    assert H.program_config(cfg).tie_embeddings is False
+
+
+@pytest.mark.parametrize("model,error", [
+    ({"d_model": 64, "num_experts": 8}, KeyError),
+    ({"moe": {"experts": 8}}, KeyError),
+    ({"window": {"size": 8}}, TypeError),
+])
+def test_program_config_refuses_unknown_keys(model, error):
+    cfg = {"name": "bad", "registry": "granite-moe-3b-a800m", "model": model}
+    with pytest.raises(error):
+        H.program_config(cfg)
 
 
 def test_reference_layout_is_the_programs():

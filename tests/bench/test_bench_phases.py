@@ -26,20 +26,27 @@ US = 1e-6
 # --------------------------------------------------------------------------
 # attribution
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("path,pass_,scope", [
+@pytest.mark.parametrize("path,pass_,scope,named", [
     ("jit(train_step)/jvp()/shard_map/while/body/closed_call/"
      "tmp.oases.sub0/tmp.oases.row_matmul/tmp.reduce/psum", "fwd",
-     "tmp.oases.sub0/tmp.oases.row_matmul/tmp.reduce"),
+     "tmp.oases.sub0/tmp.oases.row_matmul/tmp.reduce",
+     ("tmp.oases.sub0", "tmp.oases.row_matmul", "tmp.reduce")),
     ("jit(train_step)/transpose(jvp())/while/body/closed_call/checkpoint/"
      "rematted_computation/tmp.oases.sub1/dot_general", "bwd",
-     "tmp.oases.sub1"),
-    ("jit(train_step)/optim.adamw/sqrt", "opt", ""),
-    ("jit(train_step)/train.grad_accum/convert_element_type", "other", ""),
-    ("params[\\'embed\\']", "other", ""),
+     "tmp.oases.sub1", ("tmp.oases.sub1",)),
+    ("jit(train_step)/optim.adamw/sqrt", "opt", "", ("optim.adamw",)),
+    ("jit(train_step)/train.grad_accum/convert_element_type", "other", "",
+     ("train.grad_accum",)),
+    ("params[\\'embed\\']", "other", "", ()),
+    ("jit(train_step)/transpose(jvp())/reshape.799/convert.25", "bwd", "",
+     ()),
+    ("jit(step)/jvp()/moe.dispatch/tmp.reduce/moe.dispatch/psum", "fwd",
+     "tmp.reduce", ("moe.dispatch", "tmp.reduce")),
 ])
-def test_pass_and_scope_of_a_path(path, pass_, scope):
+def test_pass_and_scope_of_a_path(path, pass_, scope, named):
     assert P.pass_of(path) == pass_
     assert P.tmp_scope_of(path) == scope
+    assert P.scopes_of(path) == named
 
 
 def test_fusion_without_metadata_takes_its_root():
@@ -62,6 +69,7 @@ ENTRY %main (x: f32[8]) -> f32[8] {
     assert att["neg.1"] == ("fwd", "")
     assert att["add.4"] == ("opt", "")
     assert att["x"] == ("other", "")
+    assert P.scope_paths(text)["add.4"] == "jit(step)/optim.adamw/add"
 
 
 @pytest.fixture(scope="module")
@@ -111,10 +119,10 @@ def test_tiny_train_step_passes(tiny_step_hlo):
 # --------------------------------------------------------------------------
 # the split of a trace
 # --------------------------------------------------------------------------
-PASSES = {"fusion.7": ("fwd", "tmp.oases.sub0"),
-          "all-reduce.1": ("bwd", "tmp.oases.sub1/tmp.reduce"),
-          "add.2": ("opt", ""),
-          "collective-permute-start.1": ("fwd", "")}
+PATHS = {"fusion.7": "jit(s)/jvp()/while/body/tmp.oases.sub0/dot_general",
+         "all-reduce.1": "jit(s)/transpose(jvp())/tmp.oases.sub1/tmp.reduce/psum",
+         "add.2": "jit(s)/optim.adamw/add",
+         "collective-permute-start.1": "jit(s)/jvp()/ppermute"}
 PROGRAM = [("serve.admit", 0, 12), ("serve.dispatch", 28, 42),
            ("serve.sync", 48, 62), ("serve.emit", 88, 100)]
 
@@ -132,10 +140,8 @@ def _profile(program=()):
 
 
 def _split():
-    pd = _profile(PROGRAM)
-    tr = TR.Trace.from_profile(pd)
-    return tr, P.reduce(tr, P.program_spans(pd), PASSES,
-                        module="jit_train_step")
+    tr = TR.Trace.from_profile(_profile(PROGRAM))
+    return tr, P.reduce(tr, PATHS, module="jit_train_step")
 
 
 def test_split_by_pass_adds_up_to_busy():
@@ -147,6 +153,18 @@ def test_split_by_pass_adds_up_to_busy():
     assert by["opt"] == pytest.approx(5 * US)       # 70-75
     assert by["other"] == pytest.approx(0.0)
     assert sum(by.values()) == pytest.approx(red["busy_s_dev0"])
+
+
+def test_busy_by_named_scope():
+    """Each piece of busy time counts once under every named scope of its
+    innermost op; ops outside the step's module have none."""
+    _, ph = _split()
+    assert ph["busy_s_by_scope"] == pytest.approx({
+        "tmp.oases.sub0": 40 * US, "tmp.oases.sub1": 10 * US,
+        "tmp.reduce": 10 * US, "optim.adamw": 5 * US})
+    tr = TR.Trace.from_profile(_profile(PROGRAM))
+    assert P.reduce(tr, PATHS,
+                    module="jit_other")["busy_s_by_scope"] == {}
 
 
 def test_collectives_by_pass_and_scope():
@@ -190,9 +208,9 @@ def test_program_spans_leave_the_old_reduction_alone():
                       module="jit_train_step",
                       matmul_computations={"fused_computation.7"})
     assert after == before
-    assert P.program_spans(_profile()) == []
-    assert [n for n, _, _ in P.program_spans(_profile(PROGRAM))] == \
-        [n for n, _, _ in PROGRAM]
+    assert TR.Trace.from_profile(_profile()).program_spans == []
+    assert [n for n, _, _ in TR.Trace.from_profile(
+        _profile(PROGRAM)).program_spans] == [n for n, _, _ in PROGRAM]
 
 
 def test_innermost_nested_and_overlapping():
@@ -208,8 +226,9 @@ def test_innermost_nested_and_overlapping():
 def _contexts():
     tr, ph = _split()
     red = TR.reduce(tr, module="jit_train_step")
-    train = {"kind": "train", "trace": red, "phases": ph, "steps": 2}
-    serve = {"kind": "serve", "trace": red, "phases": ph,
+    train = {"kind": "train", "config": {}, "trace": red, "phases": ph,
+             "steps": 2}
+    serve = {"kind": "serve", "config": {}, "trace": red, "phases": ph,
              "ticks_traced": 4, "slot_ticks": 30, "ticks": 10, "slots": 4,
              "request_times": [(0.0, 0.01, 0.5), (0.1, 0.1, 0.3),
                                (0.2, None, None)]}
@@ -244,3 +263,15 @@ def test_reader_values():
     assert got["serve.queue_wait_p95_ms"] == pytest.approx(0.95 * 10.0)
     assert got["serve.prefill_p95_ms"] == pytest.approx(
         200.0 + 0.95 * (490.0 - 200.0))
+
+
+@pytest.mark.parametrize("name", sorted(P.READERS))
+def test_phase_metric_files_read_as_readers(name):
+    """Each phase metric of the result line is read by its own file
+    ``bench/metrics/<name>.py``, which gives what ``READERS`` gives."""
+    import harness as H
+    mod = H._load(H.BENCH / "metrics" / f"{name}.py",
+                  "bench_metric_" + name.replace(".", "_"))
+    for ctx in _contexts() + ({},):
+        assert mod.read(ctx) == P.READERS[name](ctx)
+    assert mod.read(_contexts()[name not in TRAIN_READERS]) is not None
